@@ -19,9 +19,7 @@ import dataclasses
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.core import compat
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # Canonical mesh axis names (see launch/mesh.py).
 POD_AXIS = "pod"
@@ -113,18 +111,12 @@ def constrain(x: jax.Array, rules: LogicalRules, *logical_axes) -> jax.Array:
     are dropped from the spec — the constraint then only refers to the
     still-auto (GSPMD) axes, e.g. the lane axis.
     """
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty:
-        return x
-    manual = compat.trace_manual_axes()
-    if manual and not hasattr(jax.sharding, "get_abstract_mesh"):
-        # pre-0.5 jax: mixing wsc with partial-manual shard_map trips a hard
-        # XLA partitioner check (IsManualSubgroup) — skip the hint; GSPMD
-        # still places the auto axes, just without our nudge.
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     auto_axes = tuple(
-        name for name, ty in zip(mesh.axis_names, compat.mesh_axis_types(mesh))
-        if ty != compat.AxisType.Manual and name not in manual)
+        name for name, ty in zip(mesh.axis_names, mesh.axis_types)
+        if ty != AxisType.Manual)
     if not auto_axes:
         return x
     rules = dataclasses.replace(rules, mesh_axes=auto_axes)

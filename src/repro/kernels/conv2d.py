@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import compat
-
 
 def _conv_kernel(x_ref, w_ref, o_ref, acc_ref, *, kh: int, kw: int,
                  bh: int, bw: int):
@@ -69,7 +67,7 @@ def conv2d(x: jax.Array, w: jax.Array, *, bh: int = 8, bw: int = 128,
                                lambda b, i, j, c: (b, i, j, c)),
         out_shape=jax.ShapeDtypeStruct((n, ho, wo, cout), x.dtype),
         scratch_shapes=[pltpu.VMEM((bh * bw, bco), jnp.float32)],
-        compiler_params=compat.pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "parallel")),
         interpret=interpret,

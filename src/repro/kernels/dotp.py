@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import compat
-
 SUBLANES = 8
 LANES = 128
 DEFAULT_STRIP = 16 * SUBLANES * LANES   # elements per grid step (16 vregs)
@@ -70,7 +68,7 @@ def dotp(a: jax.Array, b: jax.Array, *, strip: int = DEFAULT_STRIP,
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((SUBLANES, LANES), jnp.float32)],
-        compiler_params=compat.pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(a, b)

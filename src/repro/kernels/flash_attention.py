@@ -11,7 +11,9 @@ Geometry: grid = (batch·heads, Sq/bq, Sk/bk), innermost axis walks KV strips;
 carries (m, l, acc) live in VMEM scratch, exactly the operand-queue residency
 argument of the matmul kernel.  Causal and sliding-window predication (C3)
 is applied as block masks; fully-masked KV strips are skipped via ``pl.when``
-(the RVV ``vl=0`` fast path).
+(the RVV ``vl=0`` fast path).  Ragged lengths arrive zero-padded to whole
+blocks (the RVV tail): keys past ``kv_len`` are predicated off, and queries
+are right-aligned on the real lengths through ``q_offset``.
 """
 from __future__ import annotations
 
@@ -22,14 +24,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import compat
-
 NEG_INF = -1e30
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                scale: float, causal: bool, window: int | None,
-               bq: int, bk: int, nk: int, sq: int, sk: int):
+               bq: int, bk: int, nk: int, kv_len: int, q_offset: int):
     i = pl.program_id(1)
     j = pl.program_id(2)
 
@@ -40,16 +40,16 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # absolute positions; queries right-aligned with the KV sequence
-    qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + (sk - sq)
+    qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + q_offset
     kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = jnp.ones((bq, bk), jnp.bool_)
+    mask = kpos < kv_len                          # tail predication
     if causal:
         mask &= kpos <= qpos
     if window is not None:
         mask &= kpos > qpos - window
 
     # block-level skip: strip has no live element (vl == 0 fast path)
-    first_qpos = i * bq + (sk - sq)
+    first_qpos = i * bq + q_offset
     last_qpos = first_qpos + bq - 1
     live = jnp.asarray(True)
     if causal:
@@ -82,13 +82,16 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                    kv_len: int, q_offset: int,
                     causal: bool = True, window: int | None = None,
                     scale: float | None = None, bq: int = 256,
                     bk: int = 512, interpret: bool = False) -> jax.Array:
     """q: (BH, Sq, D), k/v: (BH, Sk, D) -> (BH, Sq, D).
 
     GQA head-sharing is the caller's job (repeat/arrange KV to BH).
-    Requires Sq % bq == Sk % bk == 0 (ops.py pads otherwise).
+    Requires Sq % bq == Sk % bk == 0 (ops.py pads otherwise).  ``kv_len``
+    is the number of real (unpadded) keys; ``q_offset`` is the absolute
+    position of query row 0 (real key count minus real query count).
     """
     bhq, sq, d = q.shape
     bhk, sk, dk = k.shape
@@ -101,7 +104,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     nk = sk // bk
     return pl.pallas_call(
         functools.partial(_fa_kernel, scale=scale, causal=causal,
-                          window=window, bq=bq, bk=bk, nk=nk, sq=sq, sk=sk),
+                          window=window, bq=bq, bk=bk, nk=nk, kv_len=kv_len,
+                          q_offset=q_offset),
         grid=(bhq, sq // bq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -115,7 +119,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),      # running denom l
             pltpu.VMEM((bq, d), jnp.float32),    # running accumulator
         ],
-        compiler_params=compat.pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

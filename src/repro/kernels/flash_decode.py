@@ -12,11 +12,19 @@ softmax carry; GQA grouping is preserved so the kernel reads each KV head
 once for its ``group`` query heads.  Grid = (B·KVH, Sk/bk), the KV-strip
 axis innermost with the (m, l, acc) carries in VMEM scratch.
 
+The per-row ``lengths`` vector is scalar-prefetched into SMEM
+(``PrefetchScalarGridSpec``): the whole (BKV,) vector is resident for the
+grid, and each program reads its own row's ``vl`` at ``program_id(0)``.
+
 Quantized-arena support (core/kv_format.py — the paper's multi-precision
 lanes): an optional per-row scale pair rides along as two extra VMEM
 operands and dequant fuses into the inner loop — each K/V strip widens to
-f32 *in-register* (``k.astype(f32) * ks[:, None]``) right before its MXU
-dot, so the narrow arena is the only thing that ever lives in memory.
+f32 *in-register* right before its MXU dot, so the narrow arena is the
+only thing that ever lives in memory.  Scales travel as lane-dense
+(BKV, 1, Sk) rows (a (1, bk) strip block meets the TPU's last-two-dims
+tiling rule); since a scale is per key row, it multiplies the score
+column of that key (``s * ks``) and the probability feeding that value
+row (``p * vs``) — the same products as scaling K and V rows.
 
 The KV-sequence axis is the one sharded over lanes at the system level
 (``kv_seq`` in core/lanes.py): each lane runs this kernel over its local KV
@@ -30,8 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.core import compat
 
 NEG_INF = -1e30
 
@@ -52,7 +58,7 @@ def _fd_kernel(len_ref, q_ref, k_ref, v_ref, *refs,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[0]                              # this row's vl
+    length = len_ref[pl.program_id(0)]               # this row's vl
     g = q_ref.shape[1]
     kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (g, bk), 1)
     mask = kpos < length                             # tail predication
@@ -69,11 +75,10 @@ def _fd_kernel(len_ref, q_ref, k_ref, v_ref, *refs,
         q = q_ref[0].astype(jnp.float32)             # (G, hd)
         k = k_ref[0].astype(jnp.float32)             # (bk, hd)
         v = v_ref[0].astype(jnp.float32)             # (bk, hd)
-        if scaled:
-            # fused dequant: widen in-register, scale per KV row
-            k = k * ks_ref[0][:, None]
-            v = v * vs_ref[0][:, None]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        if scaled:
+            # fused dequant: key row j's scale multiplies score column j
+            s = s * ks_ref[0]                        # (1, bk) row
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, s.max(axis=-1))
@@ -81,8 +86,9 @@ def _fd_kernel(len_ref, q_ref, k_ref, v_ref, *refs,
         p = jnp.exp(s - m_new[:, None])
         p = jnp.where(mask, p, 0.0)
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
+        pv = p * vs_ref[0] if scaled else p          # value row j's scale
         acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                        + jnp.dot(p, v,
+                        + jnp.dot(pv, v,
                                   preferred_element_type=jnp.float32))
         m_ref[...] = m_new
 
@@ -118,32 +124,33 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     scale = scale if scale is not None else d ** -0.5
     nk = sk // bk
     scaled = scales is not None
+    # index maps take the scalar-prefetched lengths ref as a trailing arg
     in_specs = [
-        pl.BlockSpec((1,), lambda b, j: (b,),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, g, d), lambda b, j: (b, 0, 0)),
-        pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0)),
-        pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0)),
+        pl.BlockSpec((1, g, d), lambda b, j, lens: (b, 0, 0)),
+        pl.BlockSpec((1, bk, d), lambda b, j, lens: (b, j, 0)),
+        pl.BlockSpec((1, bk, d), lambda b, j, lens: (b, j, 0)),
     ]
     operands = [lengths.astype(jnp.int32), q, k, v]
     if scaled:
-        in_specs += [pl.BlockSpec((1, bk), lambda b, j: (b, j)),
-                     pl.BlockSpec((1, bk), lambda b, j: (b, j))]
-        operands += [scales[0].astype(jnp.float32),
-                     scales[1].astype(jnp.float32)]
-    return pl.pallas_call(
-        functools.partial(_fd_kernel, scale=scale, window=window,
-                          bk=bk, nk=nk, scaled=scaled),
+        in_specs += [pl.BlockSpec((1, 1, bk), lambda b, j, lens: (b, 0, j)),
+                     pl.BlockSpec((1, 1, bk), lambda b, j, lens: (b, 0, j))]
+        operands += [sc.astype(jnp.float32)[:, None, :] for sc in scales]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(bkv, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, g, d), lambda b, j: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bkv, g, d), q.dtype),
+        out_specs=pl.BlockSpec((1, g, d), lambda b, j, lens: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g,), jnp.float32),       # running max m
             pltpu.VMEM((g,), jnp.float32),       # running denom l
             pltpu.VMEM((g, d), jnp.float32),     # running accumulator
-        ],
-        compiler_params=compat.pallas_compiler_params(
+        ])
+    return pl.pallas_call(
+        functools.partial(_fd_kernel, scale=scale, window=window,
+                          bk=bk, nk=nk, scaled=scaled),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bkv, g, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

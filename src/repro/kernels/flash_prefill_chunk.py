@@ -12,14 +12,16 @@ row to a strip of ``C`` query rows.
 
 Geometry: grid = (B·KVH, Sk/bk), KV-strip axis innermost with (m, l, acc)
 carries in VMEM scratch.  Queries are folded (G·C, hd) so the MXU sees one
-2-D matmul per strip; the causal boundary is dynamic (``prefix`` is a traced
-SMEM scalar — chunk position in the prompt is runtime data, not a compile
-key).  Strips entirely beyond ``prefix + C`` are skipped via ``pl.when``
-(the ``vl = 0`` fast path); rows past the live length are tail-predicated.
+2-D matmul per strip; the causal boundary is dynamic (``prefix`` is a
+scalar-prefetched SMEM vector read at ``program_id(0)`` — chunk position in
+the prompt is runtime data, not a compile key).  Strips entirely beyond
+``prefix + C`` are skipped via ``pl.when`` (the ``vl = 0`` fast path); rows
+past the live length are tail-predicated.
 
 Quantized-arena support mirrors :mod:`flash_decode`: optional per-row
-scale operands, dequant fused into the strip loop — K/V widen to f32
-in-register right before their MXU dots, never in memory.
+scale operands (lane-dense (BKV, 1, Sk) rows), dequant fused into the
+strip loop — a key row's scale multiplies its score column and its value
+row's probability, so the narrow arena is never widened in memory.
 """
 from __future__ import annotations
 
@@ -29,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.core import compat
 
 NEG_INF = -1e30
 
@@ -51,7 +51,7 @@ def _fpc_kernel(pre_ref, q_ref, k_ref, v_ref, *refs,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    prefix = pre_ref[0]                       # rows live before this chunk
+    prefix = pre_ref[pl.program_id(0)]        # rows live before this chunk
     gc = g * c
     # folded query row r = group * C + i  ->  absolute position prefix + i
     qpos = prefix + jax.lax.broadcasted_iota(jnp.int32, (gc, bk), 0) % c
@@ -70,11 +70,10 @@ def _fpc_kernel(pre_ref, q_ref, k_ref, v_ref, *refs,
         q = q_ref[0].astype(jnp.float32)      # (G*C, hd)
         k = k_ref[0].astype(jnp.float32)      # (bk, hd)
         v = v_ref[0].astype(jnp.float32)      # (bk, hd)
-        if scaled:
-            # fused dequant: widen in-register, scale per KV row
-            k = k * ks_ref[0][:, None]
-            v = v * vs_ref[0][:, None]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        if scaled:
+            # fused dequant: key row j's scale multiplies score column j
+            s = s * ks_ref[0]                 # (1, bk) row
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, s.max(axis=-1))
@@ -82,8 +81,9 @@ def _fpc_kernel(pre_ref, q_ref, k_ref, v_ref, *refs,
         p = jnp.exp(s - m_new[:, None])
         p = jnp.where(mask, p, 0.0)
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
+        pv = p * vs_ref[0] if scaled else p   # value row j's scale
         acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                        + jnp.dot(p, v,
+                        + jnp.dot(pv, v,
                                   preferred_element_type=jnp.float32))
         m_ref[...] = m_new
 
@@ -121,32 +121,33 @@ def flash_prefill_chunk(q: jax.Array, k: jax.Array, v: jax.Array,
     nk = sk // bk
     qf = q.reshape(bkv, g * c, d)
     scaled = scales is not None
+    # index maps take the scalar-prefetched prefix ref as a trailing arg
     in_specs = [
-        pl.BlockSpec((1,), lambda b, j: (b,),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, g * c, d), lambda b, j: (b, 0, 0)),
-        pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0)),
-        pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0)),
+        pl.BlockSpec((1, g * c, d), lambda b, j, pre: (b, 0, 0)),
+        pl.BlockSpec((1, bk, d), lambda b, j, pre: (b, j, 0)),
+        pl.BlockSpec((1, bk, d), lambda b, j, pre: (b, j, 0)),
     ]
     operands = [prefix.astype(jnp.int32), qf, k, v]
     if scaled:
-        in_specs += [pl.BlockSpec((1, bk), lambda b, j: (b, j)),
-                     pl.BlockSpec((1, bk), lambda b, j: (b, j))]
-        operands += [scales[0].astype(jnp.float32),
-                     scales[1].astype(jnp.float32)]
-    out = pl.pallas_call(
-        functools.partial(_fpc_kernel, scale=scale, window=window,
-                          c=c, g=g, bk=bk, nk=nk, scaled=scaled),
+        in_specs += [pl.BlockSpec((1, 1, bk), lambda b, j, pre: (b, 0, j)),
+                     pl.BlockSpec((1, 1, bk), lambda b, j, pre: (b, 0, j))]
+        operands += [sc.astype(jnp.float32)[:, None, :] for sc in scales]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(bkv, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, g * c, d), lambda b, j: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bkv, g * c, d), q.dtype),
+        out_specs=pl.BlockSpec((1, g * c, d), lambda b, j, pre: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g * c,), jnp.float32),       # running max m
             pltpu.VMEM((g * c,), jnp.float32),       # running denom l
             pltpu.VMEM((g * c, d), jnp.float32),     # running accumulator
-        ],
-        compiler_params=compat.pallas_compiler_params(
+        ])
+    out = pl.pallas_call(
+        functools.partial(_fpc_kernel, scale=scale, window=window,
+                          c=c, g=g, bk=bk, nk=nk, scaled=scaled),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bkv, g * c, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

@@ -229,22 +229,13 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     bh, sq, d = q.shape
     sk = k.shape[1]
     bq_, bk_ = min(bq, sq), min(bk, sk)
-    qp = _pad_to(q, bq_, 1)
-    # pad KV on the *left*? No: right-pad and mask via sk bound in kernel is
-    # wrong for causal alignment; instead pad KV to a multiple and extend the
-    # window mask — simplest correct: pad queries only, require sk % bk_ == 0.
-    if sk % bk_:
-        pad = (-sk) % bk_
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
-        # padded keys sit at positions > every qpos => masked off by causal;
-        # for non-causal, mask them with a window trick is unsound -> ref
-        if not causal:
-            return _blockwise_attention_ref(q[:, :sq], k[:, :sk], v[:, :sk],
-                                            causal=causal, window=window,
-                                            scale=scale, bq=bq_, bk=bk_)
-    out = _fa.flash_attention(qp, k, v, causal=causal, window=window,
-                              scale=scale, bq=bq_, bk=bk_,
+    # zero-pad both sequence axes to whole blocks (the RVV tail): the kernel
+    # predicates off keys past ``kv_len`` and right-aligns the queries on
+    # the real lengths, so padding never changes a live row
+    out = _fa.flash_attention(_pad_to(q, bq_, 1), _pad_to(k, bk_, 1),
+                              _pad_to(v, bk_, 1), causal=causal,
+                              window=window, scale=scale, bq=bq_, bk=bk_,
+                              kv_len=sk, q_offset=sk - sq,
                               interpret=(mode == "interpret"))
     return out[:, :sq]
 
@@ -559,8 +550,9 @@ def ssd(x: jax.Array, log_a: jax.Array, B: jax.Array, C: jax.Array, *,
 
     ``initial_state`` (BH, N, P) seeds the recurrence (serving's chunked
     prefill threads it across prompt chunks); supported by every path —
-    the Pallas kernel takes it as a VMEM-seeded operand, so stripmined
-    SSM prefill does not fall back to the jnp path on TPU."""
+    the Pallas kernel takes it as a VMEM-seeded operand, and ragged
+    lengths are zero-padded to whole chunks, so SSM prefill never falls
+    back to the jnp path on TPU."""
     mode = mode or _resolved()
     if mode == "ref":
         return _chunked_ssd_ref(x, log_a, B, C, chunk=chunk,
@@ -568,11 +560,13 @@ def ssd(x: jax.Array, log_a: jax.Array, B: jax.Array, C: jax.Array, *,
     s = x.shape[1]
     chunk_ = min(chunk, s)
     if s % chunk_:
-        return _chunked_ssd_ref(x, log_a, B, C, chunk=chunk,
-                                initial_state=initial_state)
-    return _ssd.ssd(x, log_a, B, C, chunk=chunk_,
-                    initial_state=initial_state,
-                    interpret=(mode == "interpret"))
+        # zero-pad to whole chunks: log_a = 0 (decay 1) with x = B = 0 adds
+        # nothing to the carried state, and the padded y rows are dropped
+        x, log_a, B, C = (_pad_to(t, chunk_, 1) for t in (x, log_a, B, C))
+    y, st = _ssd.ssd(x, log_a, B, C, chunk=chunk_,
+                     initial_state=initial_state,
+                     interpret=(mode == "interpret"))
+    return y[:, :s], st
 
 
 def ssd_decode_step(x_t, log_a_t, B_t, C_t, state):

@@ -12,6 +12,8 @@ The SSD algorithm *is* Ara's execution model applied to a recurrence:
 
 Grid = (batch·heads, S/Q), sequential inner axis; the carry state lives in a
 VMEM scratch that persists across grid steps of the same (batch·head) row.
+The log-decay travels as a lane-dense (BH, 1, S) row, so its (1, Q) strip
+block meets the TPU's last-two-dims tiling rule.
 
 Semantics (dt pre-folded into x and the log-decay):
   state_j = exp(la_j)·state_{j-1} + B_j ⊗ x_j ;  y_j = C_j · state_j
@@ -24,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.core import compat
 
 NEG_INF = -1e30
 
@@ -42,29 +42,36 @@ def _ssd_kernel(x_ref, la_ref, b_ref, c_ref, st0_ref, y_ref, st_out_ref,
         state_ref[...] = st0_ref[0].astype(jnp.float32)
 
     x = x_ref[0].astype(jnp.float32)       # (Q, P)
-    la = la_ref[0].astype(jnp.float32)     # (Q,)
+    la = la_ref[0].astype(jnp.float32)     # (1, Q)
     B = b_ref[0].astype(jnp.float32)       # (Q, N)
     C = c_ref[0].astype(jnp.float32)       # (Q, N)
     q = x.shape[0]
 
-    cum = jnp.cumsum(la)                   # inclusive within-chunk decay
-    total = cum[-1]
-
-    # intra-chunk (dense, MXU): scores[i,j] = (C_i·B_j)·exp(cum_i - cum_j), j<=i
-    seg = cum[:, None] - cum[None, :]
+    # inclusive within-chunk decay (Mosaic has no cumsum): as a column, a
+    # masked lane reduction over the lower triangle; as a row, the same
+    # triangle applied by a full-f32-precision matmul
     ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    seg = jnp.where(ii >= jj, seg, NEG_INF)
+    lower = ii >= jj
+    cum_col = jnp.sum(jnp.where(lower, la, 0.0), axis=1,
+                      keepdims=True)                      # (Q, 1)
+    cum_row = jnp.dot(la, jnp.where(ii <= jj, 1.0, 0.0),
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)  # (1, Q)
+    total = jnp.sum(la, axis=1, keepdims=True)            # (1, 1)
+
+    # intra-chunk (dense, MXU): scores[i,j] = (C_i·B_j)·exp(cum_i - cum_j), j<=i
+    seg = jnp.where(lower, cum_col - cum_row, NEG_INF)
     scores = jnp.dot(C, B.T, preferred_element_type=jnp.float32) * jnp.exp(seg)
     y = jnp.dot(scores, x, preferred_element_type=jnp.float32)
 
     # carry-in from previous chunks (slide step)
-    state = state_ref[...]                 # (N, P)
-    y += jnp.dot(C * jnp.exp(cum)[:, None], state,
+    state = state_ref[...]                  # (N, P)
+    y += jnp.dot(C * jnp.exp(cum_col), state,
                  preferred_element_type=jnp.float32)
 
     # state update for the next chunk
-    weights = jnp.exp(total - cum)[:, None] * B         # (Q, N)
+    weights = jnp.exp(total - cum_col) * B  # (Q, N)
     state_ref[...] = jnp.exp(total) * state + jnp.dot(
         weights.T, x, preferred_element_type=jnp.float32)
 
@@ -99,7 +106,7 @@ def ssd(x: jax.Array, log_a: jax.Array, B: jax.Array, C: jax.Array, *,
         grid=(bh, nchunks),
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk), lambda b, c: (b, c)),
+            pl.BlockSpec((1, 1, chunk), lambda b, c: (b, 0, c)),
             pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, n, p), lambda b, c: (b, 0, 0)),
@@ -113,8 +120,8 @@ def ssd(x: jax.Array, log_a: jax.Array, B: jax.Array, C: jax.Array, *,
             jax.ShapeDtypeStruct((bh, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=compat.pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(x, log_a, B, C, st0)
+    )(x, log_a[:, None, :], B, C, st0)
     return y, st

@@ -28,7 +28,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import SHAPES, ShapeConfig
-from repro.core import compat, lanes, roofline
+from repro.core import lanes, roofline
 from repro.launch.mesh import make_production_mesh, chips
 from repro.models import partition, registry
 from repro.optim import adamw_init
@@ -106,7 +106,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
             aef = jax.eval_shape(
                 lambda p: ef_state_template(p, mesh), aparams)
             args = (aparams, aopt, aef, specs)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = step.lower(*args)
     elif shape.kind == "prefill":
         cshard = _named(mesh, partition.cache_specs(specs["cache"], rules, mesh=mesh))
@@ -129,7 +129,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
             prefill,
             in_shardings=(pshard, tokshard, cshard, extra_shard),
             out_shardings=(logits_shard, cshard))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jfn.lower(aparams, specs["tokens"], specs["cache"],
                                 extras)
     else:   # decode
@@ -148,7 +148,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
             in_shardings=(pshard, bshard, cshard, bshard),
             out_shardings=(logits_shard, cshard),
             donate_argnums=(2,))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jfn.lower(aparams, specs["token_t"], specs["cache"],
                                 specs["pos"])
     t_lower = time.time() - t0
@@ -178,7 +178,7 @@ def analyse(compiled, meta, cfg, shape) -> dict:
         wire_bytes_per_chip=cost.wire_bytes,
         collective_counts=dict(cost.collective_counts),
         model_flops_per_chip=mf / meta["chips"])
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     legacy = roofline.RooflineTerms(
         flops_per_chip=float(ca.get("flops", 0.0)),
         hbm_bytes_per_chip=float(ca.get("bytes accessed", 0.0)),
@@ -229,7 +229,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, out_dir: str,
                 print(f"[{cell_id}] memory_analysis:",
                       compiled.memory_analysis())
                 print(f"[{cell_id}] cost_analysis keys:",
-                      sorted(compat.cost_analysis(compiled).keys())[:12])
+                      sorted(compiled.cost_analysis().keys())[:12])
         except Exception as e:
             rec = {"arch": arch, "shape": shape_name, "mesh": mesh_name,
                    "failed": True, "error": f"{type(e).__name__}: {e}",

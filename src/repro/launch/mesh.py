@@ -13,9 +13,7 @@ touches jax device state (the dry-run must set
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-from repro.core.compat import AxisType, make_mesh
+from jax.sharding import AxisType, Mesh
 
 SINGLE_POD_SHAPE = (16, 16)
 MULTI_POD_SHAPE = (2, 16, 16)
@@ -24,14 +22,25 @@ MULTI_POD_SHAPE = (2, 16, 16)
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = MULTI_POD_SHAPE if multi_pod else SINGLE_POD_SHAPE
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes,
-                     axis_types=(AxisType.Auto,) * len(shape))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_test_mesh(shape=(1, 1), axes=("data", "model")) -> Mesh:
     """Small mesh over however many (CPU) devices the test process has."""
-    return make_mesh(shape, axes,
-                     axis_types=(AxisType.Auto,) * len(shape))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(shape))
+
+
+def replica_mesh(replicas: int) -> Mesh:
+    """The serving router's mesh: a ``data`` axis of one device per replica
+    over the first ``min(replicas, device_count)`` devices, ``model`` = 1.
+    ``data_shards`` then hands each replica exactly one device (cycling
+    when replicas outnumber the devices)."""
+    n = min(replicas, jax.device_count())
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=jax.devices()[:n])
 
 
 def chips(mesh: Mesh) -> int:

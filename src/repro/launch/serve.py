@@ -2,6 +2,10 @@
 
 ``python -m repro.launch.serve --arch <id> --requests 8 --gen 32``
 
+The arch is built reduced (the smoke-test widths) unless ``--full`` asks for
+its published widths; the persistent compilation cache follows
+``launch/compile_cache.py``.
+
 Built on :mod:`repro.runtime.serving`: a request queue + scheduler admits
 and retires decode sequences every step, a slot-based paged KV cache holds
 the batch, and decode steps flow through a ``DispatchQueue`` so the host
@@ -55,8 +59,9 @@ Multi-replica knobs (the router; see serving/README.md):
 
   * ``--replicas N`` serves the workload over N engine replicas behind a
     :class:`~repro.runtime.serving.Router` — independent arenas /
-    schedulers / dispatch queues sharing one model object (and therefore
-    one set of compiled executables).  A per-replica stats line is
+    schedulers / dispatch queues sharing one model object, replica *r*
+    committed to device *r* (``launch.mesh.replica_mesh``; replicas share
+    devices when they outnumber them).  A per-replica stats line is
     printed after the run.  Streams are bit-identical to ``--replicas 1``
     under every placement policy: the PRNG folds only (seed, position).
   * ``--placement least-pressure|round-robin|affinity`` picks where each
@@ -71,6 +76,8 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import replica_mesh
 from repro.models import registry
 from repro.runtime.serving import (DEFAULT_BUCKETS, PLACEMENT_POLICIES,
                                    EngineConfig, GREEDY, HealthConfig,
@@ -112,6 +119,24 @@ def make_engine(bundle, params, *, config: EngineConfig = None,
     elif fields:
         config = config.replace(**fields)
     return ServingEngine(bundle.model, bundle.cfg, params, config=config)
+
+
+def make_router(bundle, params, *, config: EngineConfig, replicas: int,
+                placement: str = "least-pressure") -> Router:
+    """``replicas`` engines behind a :class:`Router`, replica *r* on the
+    *r*-th device of :func:`~repro.launch.mesh.replica_mesh`."""
+    return Router(bundle.model, bundle.cfg, params,
+                  config=RouterConfig(replicas=replicas, placement=placement,
+                                      engine=config),
+                  mesh=replica_mesh(replicas))
+
+
+def arena_rows(max_prompt: int, gen: int, chunks=None,
+               prefix: int = 0) -> int:
+    """Slot arena depth for a workload: the longest prompt (+ VLM prefix)
+    and its generation, the chunk padding slack (always under the smallest
+    bucket) and one row."""
+    return max_prompt + prefix + gen + (min(chunks) if chunks else 0) + 1
 
 
 def sampling_plan(n_requests: int, *, temperature: float, top_k: int,
@@ -313,10 +338,13 @@ def main(argv=None):
                    help="router placement policy (only with --replicas "
                         "> 1); token streams are bit-identical under "
                         "every choice")
-    p.add_argument("--reduced", action="store_true", default=True)
+    p.add_argument("--full", action="store_true",
+                   help="build the arch at its published widths (default: "
+                        "the reduced smoke-test config)")
     args = p.parse_args(argv)
 
-    bundle = registry.build(args.arch, reduced=args.reduced)
+    enable_compile_cache()
+    bundle = registry.build(args.arch, reduced=not args.full)
     cfg = bundle.cfg
     params = jax.jit(bundle.model.init)(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
@@ -360,14 +388,10 @@ def main(argv=None):
         ).astype(np.float32)
     prefix = cfg.n_patch_tokens if cfg.family == "vlm" else 0
 
-    # arena sized to the longest prompt in the workload (+ chunk padding,
-    # which stays under the smallest bucket)
-    max_prompt = max(lens)
-    pad_slack = min(chunks) if chunks else 0
     donate = {"auto": "auto", "on": True, "off": False}[args.donate]
     econfig = EngineConfig(
         max_slots=args.slots or args.requests,
-        max_seq=max_prompt + prefix + args.gen + pad_slack + 1,
+        max_seq=arena_rows(max(lens), args.gen, chunks, prefix),
         depth=args.depth, page_size=args.page_size,
         num_pages=args.pages, prefill_chunks=chunks,
         prefill_budget=args.prefill_budget,
@@ -386,10 +410,9 @@ def main(argv=None):
     if args.replicas > 1:
         # sessions cycle over 2x the fleet so the affinity policy has
         # pins to honor without starving any replica of first contact
-        router = Router(bundle.model, cfg, params,
-                        config=RouterConfig(replicas=args.replicas,
-                                            placement=args.placement,
-                                            engine=econfig))
+        router = make_router(bundle, params, config=econfig,
+                             replicas=args.replicas,
+                             placement=args.placement)
         for i in range(args.requests):
             router.submit(Request(
                 uid=i, prompt=prompts[i],

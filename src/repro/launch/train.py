@@ -1,9 +1,8 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
-On this CPU container it trains *reduced* configs end-to-end (the full
-configs are exercised abstractly by the dry-run); on a real TPU cluster the
-same entry point runs the full config — the mesh adapts to
-``jax.device_count()``.
+By default it trains the *reduced* config of the arch end-to-end; ``--full``
+builds the published widths — the mesh adapts to ``jax.device_count()``.
+The persistent compilation cache follows ``launch/compile_cache.py``.
 
 Demonstrates the full production loop: sharded init, synthetic data
 pipeline with prefetch, the selected gradient-reduction schedule (C4),
@@ -18,6 +17,7 @@ import jax
 from repro.configs.base import ShapeConfig
 from repro.data import make_pipeline
 from repro.data.pipeline import family_extras_fn
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models import registry
 from repro.runtime import Trainer, TrainConfig
@@ -29,8 +29,9 @@ def main(argv=None):
     p.add_argument("--steps", type=int, default=100)
     p.add_argument("--batch", type=int, default=8)
     p.add_argument("--seq", type=int, default=128)
-    p.add_argument("--reduced", action="store_true", default=True)
-    p.add_argument("--full", dest="reduced", action="store_false")
+    p.add_argument("--full", action="store_true",
+                   help="build the arch at its published widths (default: "
+                        "the reduced smoke-test config)")
     p.add_argument("--reduction", default="gspmd",
                    choices=["gspmd", "hier", "hier_tree", "hier_ef8"])
     p.add_argument("--remat", default="full",
@@ -45,12 +46,13 @@ def main(argv=None):
     p.add_argument("--model-axis", type=int, default=1)
     args = p.parse_args(argv)
 
+    enable_compile_cache()
     ndev = jax.device_count()
     data = args.data_axis or (ndev // args.model_axis)
     mesh = make_test_mesh((data, args.model_axis), ("data", "model"))
     print(f"mesh: data={data} model={args.model_axis} ({ndev} devices)")
 
-    bundle = registry.build(args.arch, reduced=args.reduced)
+    bundle = registry.build(args.arch, reduced=not args.full)
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
     tcfg = TrainConfig(
         num_steps=args.steps, reduction=args.reduction, remat=args.remat,

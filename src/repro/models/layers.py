@@ -14,8 +14,9 @@ import jax
 import jax.numpy as jnp
 
 from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import AxisType
 
-from repro.core import compat, kv_format as kvf, lanes
+from repro.core import kv_format as kvf, lanes
 from repro.kernels import ops
 
 RULES = lanes.LogicalRules()
@@ -62,18 +63,17 @@ def tp_boundary_dot(h, w, adtype, rules):
     """Lane-contracted projection at a TP boundary: out = h @ w, with the
     contraction dim lane-sharded.  Output is seq_tp-sharded (or replicated
     when seq_tp is off / no lane axis is present)."""
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     use_explicit = (
-        TP_REDUCE == "bf16_scatter" and compat.PARTIAL_AUTO_SHARD_MAP
+        TP_REDUCE == "bf16_scatter"
         and h.ndim == 3
-        and mesh is not None and not mesh.empty
+        and not mesh.empty
         and lanes.LANE_AXIS in mesh.axis_names
         and mesh.shape[lanes.LANE_AXIS] > 1
         and h.shape[1] % mesh.shape[lanes.LANE_AXIS] == 0
         and h.shape[-1] % mesh.shape[lanes.LANE_AXIS] == 0
-        and compat.mesh_axis_types(mesh)[
-            mesh.axis_names.index(lanes.LANE_AXIS)]
-        != compat.AxisType.Manual)
+        and mesh.axis_types[mesh.axis_names.index(lanes.LANE_AXIS)]
+        != AxisType.Manual)
     if not use_explicit:
         seq_ax = "seq_tp" if h.ndim == 3 else None
         if TP_REDUCE == "bf16_dot":
@@ -100,7 +100,7 @@ def tp_boundary_dot(h, w, adtype, rules):
                                    scatter_dimension=1, tiled=True)
         return out.astype(adtype)
 
-    out = compat.shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None, lanes.LANE_AXIS), P(lanes.LANE_AXIS, None)),
         out_specs=P(None, lanes.LANE_AXIS, None),

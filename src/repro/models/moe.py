@@ -21,8 +21,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
-from repro.core import compat, lanes
+from repro.core import lanes
 from repro.models import layers as L
 
 RULES = L.RULES
@@ -75,14 +76,13 @@ def set_moe_dispatch(mode: str) -> None:
 
 def moe_mlp_apply(p, cfg, x, *, rules=RULES):
     """x: (B, S, d) -> (y, aux_loss).  Dispatch per MOE_DISPATCH."""
-    if MOE_DISPATCH == "local" and compat.PARTIAL_AUTO_SHARD_MAP:
-        mesh = compat.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
+    if MOE_DISPATCH == "local":
+        mesh = jax.sharding.get_abstract_mesh()
+        if not mesh.empty:
             dp = tuple(a for a in (lanes.POD_AXIS, lanes.DATA_AXIS)
                        if a in mesh.axis_names
-                       and compat.mesh_axis_types(mesh)[
-                           mesh.axis_names.index(a)]
-                       != compat.AxisType.Manual
+                       and mesh.axis_types[mesh.axis_names.index(a)]
+                       != AxisType.Manual
                        and mesh.shape[a] > 1)
             dp_size = 1
             for a in dp:
@@ -106,7 +106,7 @@ def moe_mlp_apply(p, cfg, x, *, rules=RULES):
                     y, aux = _moe_mlp_global(p_, cfg, x_loc, rules=rules)
                     return y.astype(x.dtype), jax.lax.pmean(aux, dp)
 
-                return compat.shard_map(
+                return jax.shard_map(
                     body, mesh=mesh,
                     in_specs=(P(), P(dp if len(dp) > 1 else dp[0])),
                     out_specs=(P(dp if len(dp) > 1 else dp[0]), P()),

@@ -104,14 +104,14 @@ from repro.runtime.serving.speculative import SpecController
 
 # Buffer-donation pay-off threshold.  Donation removes the output-copy of
 # every donated buffer (the arena stops being re-materialised per step) but
-# costs the runtime fixed per-call ownership bookkeeping — measured at
-# ~25-80 us/call on the jax-0.4.37 CPU client, vs ~100 us/MB saved copy.
-# Small test/CI arenas therefore run *faster* undonated, while any
-# production-sized arena (the regime the zero-copy rewrite targets —
-# max_slots·max_seq in the thousands of rows) pays the fixed cost back many
-# times over.  ``donate="auto"`` switches on this arena-size threshold;
-# the structural zero-copy paths (chunk-rows-only writes, no
-# extract/insert round-trip) are unconditional — they win at every size.
+# costs the runtime fixed per-call ownership bookkeeping, so tiny test/CI
+# arenas can run faster undonated while any production-sized arena
+# (max_slots·max_seq in the thousands of rows) pays the fixed cost back
+# many times over.  ``donate="auto"`` switches on this arena-size
+# threshold; the 1 MiB value was tuned on a CPU client and has not been
+# measured on a chip.  The structural zero-copy paths (chunk-rows-only
+# writes, no extract/insert round-trip) are unconditional — they win at
+# every size.
 DONATE_MIN_BYTES: int = 1 << 20
 
 
@@ -436,6 +436,13 @@ class ServingEngine:
     per-draw key folds only (request seed, absolute position) — see
     :mod:`repro.runtime.serving.sampling`.
 
+    ``device`` (optional): the one device this engine runs on.  Params and
+    every piece of device state (slot vectors, arena, templates) are
+    committed to it at construction, so each compiled step — whose other
+    inputs are uncommitted host scalars — executes there.  ``None`` leaves
+    placement to JAX's default device.  A router gives each replica its
+    own chip this way.
+
     Construction: ``ServingEngine(model, cfg, params,
     config=EngineConfig(...))`` is the documented path — every knob above
     is an :class:`EngineConfig` field.  Legacy keyword construction
@@ -445,7 +452,7 @@ class ServingEngine:
 
     def __init__(self, model, cfg, params, *,
                  config: Optional[EngineConfig] = None,
-                 clock=None, **legacy):
+                 clock=None, device=None, **legacy):
         # ``clock``: the engine's wall-clock source (default
         # time.perf_counter) — drives submitted_at / ttft / deadlines, so
         # deadline tests inject a fake clock and replay expiries
@@ -467,7 +474,8 @@ class ServingEngine:
         self.config = config
         self.model = model
         self.cfg = cfg
-        self.params = params
+        self.device = device
+        self.params = self._on_device(lambda: params)
         max_slots = self.max_slots = config.max_slots
         max_seq = self.max_seq = config.max_seq
         self.depth = config.depth
@@ -553,7 +561,10 @@ class ServingEngine:
         self._share = ({"src": jnp.arange(max_slots, dtype=jnp.int32),
                         "len": jnp.zeros((max_slots,), jnp.int32)}
                        if self.prefix_sharing else None)
-        self._cache = model.init_cache(max_slots, max_seq, **self._cache_kw)
+        (self._tokens, self._pos, self._active, self._samp, self._share,
+         self._cache) = self._on_device(lambda: (
+            self._tokens, self._pos, self._active, self._samp, self._share,
+            model.init_cache(max_slots, max_seq, **self._cache_kw)))
 
         self.arena_bytes = sum(
             leaf.nbytes for leaf in jax.tree.leaves(self._cache))
@@ -585,7 +596,8 @@ class ServingEngine:
         # batch=1 zero cache reused by every monolithic admission (purely
         # functional — prefill returns a new cache, this one is never
         # written and never donated)
-        self._one_cache = model.init_cache(1, max_seq, **self._cache_kw)
+        self._one_cache = self._on_device(
+            lambda: model.init_cache(1, max_seq, **self._cache_kw))
         if prefill_chunks is not None:
             self._chunk_fn = (
                 _compiled_prefill_chunk_shared(model, self.donate)
@@ -617,10 +629,12 @@ class ServingEngine:
                 raise ValueError(
                     "speculative decoding needs the chunked-prefill and "
                     "arena-decode hooks on both target and draft")
-            self._draft_params = jax.jit(dm.init)(
-                jax.random.PRNGKey(config.speculative.draft_seed))
-            self._draft_cache = dm.init_cache(max_slots, max_seq)
-            self._draft_one_cache = dm.init_cache(1, max_seq)
+            self._draft_params, self._draft_cache, self._draft_one_cache = \
+                self._on_device(lambda: (
+                    jax.jit(dm.init)(
+                        jax.random.PRNGKey(config.speculative.draft_seed)),
+                    dm.init_cache(max_slots, max_seq),
+                    dm.init_cache(1, max_seq)))
             self._draft_prefill_fn = _compiled_prefill(dm)
             if prefill_chunks is not None:
                 self._draft_chunk_fn = _compiled_prefill_chunk(
@@ -689,6 +703,16 @@ class ServingEngine:
             self.stats.update({"spec_rounds": 0, "spec_draft_steps": 0,
                                "spec_verify_calls": 0,
                                "spec_verify_compiles": 0})
+
+    def _on_device(self, make):
+        """``make()``'s device state, built on this engine's device and
+        committed to it (left as built when the engine has none).  Building
+        under the device — not on the default device and then copying —
+        keeps other replicas' arenas off the default device."""
+        if self.device is None:
+            return make()
+        with jax.default_device(self.device):
+            return jax.device_put(make(), self.device)
 
     def _submit_decode(self, state):
         if self._use_sampling:

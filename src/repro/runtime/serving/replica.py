@@ -11,7 +11,8 @@ rung, prefix residency) and the evacuation hook for drain-with-migration.
 
 All replicas are built from the *same* model object and parameter tree, so
 the :func:`~repro.runtime.serving.engine._per_model` jit caches are shared
-— N replicas compile exactly as many executables as one — and every
+— N replicas on one device compile exactly as many executables as one
+(on N devices, each device compiles its own) — and every
 replica resolves default seeds from the same ``base_seed``.  Together with
 the (seed, absolute position) PRNG contract that makes every stream
 placement-invariant: the router can put a request anywhere, or move it
@@ -60,17 +61,26 @@ class Replica:
     """A router-owned engine: placement signals + lifecycle hooks.
 
     ``devices`` (optional) is this replica's slice of the mesh's ``data``
-    axis (see ``launch.mesh.data_shards``); on a one-device test host all
-    replicas share the device and the assignment is advisory.
+    axis (see ``launch.mesh.data_shards``): exactly one device, which the
+    engine's params and arena are committed to.  When replicas outnumber
+    the devices, shards cycle and replicas share a device (the one-device
+    test host).  A replica spanning several devices would need its model
+    sharded over them, which the engine does not do, so it is refused.
     """
 
     def __init__(self, rid: int, model, cfg, params, *,
                  config: EngineConfig, clock=None, devices=None):
         self.rid = rid
         self.devices = list(devices) if devices else None
+        if self.devices and len(self.devices) > 1:
+            raise ValueError(
+                f"replica {rid} was given {len(self.devices)} devices; a "
+                f"replica runs on one device (build the mesh's data axis "
+                f"with one device per replica)")
         self._clock = clock
-        self.engine = ServingEngine(model, cfg, params, config=config,
-                                    clock=clock)
+        self.engine = ServingEngine(
+            model, cfg, params, config=config, clock=clock,
+            device=self.devices[0] if self.devices else None)
 
     # -- placement signals ---------------------------------------------------
     @property

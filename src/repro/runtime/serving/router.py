@@ -108,13 +108,14 @@ class Router:
     """N engine replicas behind one submit/step/run surface.
 
     ``model``/``cfg``/``params`` are shared by every replica — sharing the
-    model *object* shares the per-model jit caches, so the fleet compiles
-    exactly as many executables as a single engine, and sharing
+    model *object* shares the per-model jit caches, so a fleet on one
+    device compiles exactly as many executables as a single engine (each
+    further device compiles its own), and sharing
     ``base_seed`` makes default-seed sampling placement-invariant.
 
-    ``mesh`` (optional): replicas are assigned contiguous ``data``-axis
-    device shards via ``launch.mesh.data_shards`` (advisory on a
-    one-device host).  ``clock_factory(rid)`` (optional) builds each
+    ``mesh`` (optional): replica *r* is placed on the *r*-th ``data``-axis
+    device shard (``launch.mesh.data_shards``) — its params and arena are
+    committed to that device; shards cycle when replicas outnumber them.  ``clock_factory(rid)`` (optional) builds each
     replica's clock — e.g. ``lambda rid: StepClock()`` for deterministic
     step-denominated TTFT.  ``replica_factory`` (optional) overrides
     replica construction; property tests inject duck-typed fakes here.

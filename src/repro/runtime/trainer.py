@@ -30,7 +30,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import chaining, compat, lanes, reduction
+from repro.core import chaining, lanes, reduction
 from repro.models import partition
 from repro.optim import (AdamWConfig, adamw_init, adamw_update,
                          cosine_schedule, ef_int8_init, ef_int8_compress_psum)
@@ -172,13 +172,6 @@ def make_train_step(model, mesh: Mesh, tcfg: TrainConfig,
         metrics.update(loss=loss, lr=lr)
         return params, opt, metrics
 
-    if tcfg.reduction != "gspmd" and not compat.PARTIAL_AUTO_SHARD_MAP:
-        import warnings
-        warnings.warn(
-            f"reduction={tcfg.reduction!r} needs partial-auto shard_map "
-            "(jax >= 0.5); falling back to gspmd", RuntimeWarning)
-        tcfg = dataclasses.replace(tcfg, reduction="gspmd")
-
     if tcfg.reduction == "gspmd":
         def step(params, opt, batch):
             loss, grads = grads_of(params, batch)
@@ -201,7 +194,7 @@ def make_train_step(model, mesh: Mesh, tcfg: TrainConfig,
                     return loss, grads, ef
 
                 ef_spec = jax.tree.map(lambda _: P(data_axis), ef)
-                loss, grads, ef = compat.shard_map(
+                loss, grads, ef = jax.shard_map(
                     shard_fn, mesh=mesh,
                     in_specs=(rep_wrt_dp, ef_spec, batch_spec),
                     out_specs=(P(), rep_wrt_dp, ef_spec),
@@ -221,7 +214,7 @@ def make_train_step(model, mesh: Mesh, tcfg: TrainConfig,
                     loss = lax.pmean(loss, dp_axes)
                     return loss, grads
 
-                loss, grads = compat.shard_map(
+                loss, grads = jax.shard_map(
                     shard_fn, mesh=mesh,
                     in_specs=(rep_wrt_dp, batch_spec),
                     out_specs=(P(), rep_wrt_dp),
@@ -318,7 +311,7 @@ class Trainer:
     # -- state ---------------------------------------------------------------
     def init_state(self) -> dict:
         key = jax.random.PRNGKey(self.tcfg.seed)
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             params = jax.jit(
                 self.model.init,
                 out_shardings=self.shardings["params"])(key)
@@ -370,7 +363,7 @@ class Trainer:
             state, start_step = self.maybe_restore()
         history = []
         it = iter(batches)
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             for step in range(start_step, tcfg.num_steps):
                 batch = next(it)
                 t0 = time.perf_counter()

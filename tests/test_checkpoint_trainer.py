@@ -152,18 +152,16 @@ def test_elastic_remesh_roundtrip():
 # distributed trainer (subprocess, 8 devices): all reduction modes agree
 # ---------------------------------------------------------------------------
 
-@pytest.mark.skipif(jax.__version_info__ < (0, 5, 0),
-                    reason="partial-auto shard_map crashes the XLA bundled with jax<0.5")
 def test_reduction_modes_agree(run8):
     run8("""
 import jax, numpy as np
-from repro.core.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.models import registry
 from repro.runtime import Trainer, TrainConfig
 from repro.data import make_pipeline
 from repro.configs.base import ShapeConfig
 
-mesh = make_mesh((2, 2, 2), ("pod", "data", "model"),
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
                  axis_types=(AxisType.Auto,)*3)
 b = registry.build("llama3.2-3b", reduced=True)
 shape = ShapeConfig("tiny", 32, 8, "train")

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.core import compat, hlo_analysis, roofline
+from repro.core import hlo_analysis, roofline
 
 
 def _cost(fn, *specs):
@@ -37,7 +37,7 @@ def test_scan_multiplies_by_trip_count():
     # the built-in cost_analysis undercounts by ~L — what we're fixing
     lowered = jax.jit(f).lower(jax.ShapeDtypeStruct((8, 64), jnp.float32),
                                jax.ShapeDtypeStruct((L, 64, 64), jnp.float32))
-    builtin = compat.cost_analysis(lowered.compile())["flops"]
+    builtin = lowered.compile().cost_analysis()["flops"]
     assert builtin < c.dot_flops / 4
 
 

@@ -84,8 +84,6 @@ def test_conv2d_vs_ref(hw, cin, cout, k):
                                            (False, None)])
 @pytest.mark.parametrize("sq,sk", [(64, 64), (33, 33), (1, 128)])
 def test_attention_vs_ref(mode, causal, window, sq, sk):
-    if mode == "interpret" and not causal and sk % 512:
-        pytest.skip("non-causal ragged falls back to ref (tested there)")
     if sq != sk and causal is False:
         pytest.skip("cross-attention covered by (False, None) square")
     d = 16
@@ -96,6 +94,22 @@ def test_attention_vs_ref(mode, causal, window, sq, sk):
                         bq=32, bk=32)
     want = jax.vmap(functools.partial(ref.attention, causal=causal,
                                       window=window))(q, k, v)
+    np.testing.assert_allclose(out, want, rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("sq,sk,bq,bk", [(40, 40, 32, 64), (24, 40, 16, 16)])
+def test_attention_ragged_blocks_interpret(causal, sq, sk, bq, bk):
+    """Ragged query and key lengths are zero-padded to whole blocks on the
+    kernel path; padded keys are predicated off and queries stay
+    right-aligned on the *real* lengths, whatever the two block sizes."""
+    d = 16
+    q = _rand(KEY, (2, sq, d), jnp.float32)
+    k = _rand(jax.random.PRNGKey(1), (2, sk, d), jnp.float32)
+    v = _rand(jax.random.PRNGKey(2), (2, sk, d), jnp.float32)
+    out = ops.attention(q, k, v, causal=causal, mode="interpret",
+                        bq=bq, bk=bk)
+    want = jax.vmap(functools.partial(ref.attention, causal=causal))(q, k, v)
     np.testing.assert_allclose(out, want, rtol=2e-3, atol=2e-3)
 
 
@@ -123,7 +137,7 @@ def test_attention_decode_right_alignment():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("mode", ["interpret", "ref"])
-@pytest.mark.parametrize("s,chunk", [(64, 16), (64, 64), (48, 16)])
+@pytest.mark.parametrize("s,chunk", [(64, 16), (64, 64), (48, 16), (40, 16)])
 def test_ssd_vs_ref(mode, s, chunk):
     bh, p, n = 3, 16, 8
     x = _rand(KEY, (bh, s, p), jnp.float32)

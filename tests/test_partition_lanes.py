@@ -3,9 +3,9 @@ cache specs, dispatch queue."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro.core import compat, dispatch, lanes
+from repro.core import dispatch, lanes
 from repro.launch.mesh import make_test_mesh
 from repro.models import partition, registry
 
@@ -54,7 +54,7 @@ def test_param_logical_axes_moe_ssm():
 
 
 def test_fit_spec_divisibility():
-    mesh = compat.abstract_mesh((2, 2), ("data", "model"))
+    mesh = AbstractMesh((2, 2), ("data", "model"))
     # 50280 % 2 == 0 -> kept; 51 % 2 == 1 -> dropped
     assert partition.fit_spec(P("model", None), (50280, 64), mesh) == \
         P("model", None)
@@ -66,7 +66,7 @@ def test_fit_spec_divisibility():
 
 
 def test_zero1_spec_adds_data_only_when_divisible():
-    mesh = compat.abstract_mesh((2, 1), ("data", "model"))
+    mesh = AbstractMesh((2, 1), ("data", "model"))
     sp = partition.zero1_spec(P(None, "model"), (4096, 64), mesh)
     assert sp == P("data", "model")
     sp = partition.zero1_spec(P(None, None), (4097, 4096), mesh)

@@ -68,14 +68,14 @@ def test_flash_triangular_skips_masked_blocks():
 def test_seq_parallel_matches_baseline(run8):
     run8("""
 import jax, numpy as np
-from repro.core.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.models import registry
 from repro.core import lanes
 from repro.runtime import Trainer, TrainConfig
 from repro.data import make_pipeline
 from repro.configs.base import ShapeConfig
 
-mesh = make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
 shape = ShapeConfig("tiny", 64, 4, "train")
 losses = {}
 for name, rules in [("base", lanes.LogicalRules()),
@@ -90,18 +90,16 @@ print("OK")
 """, timeout=1200)
 
 
-@pytest.mark.skipif(jax.__version_info__ < (0, 5, 0),
-                    reason="partial-auto shard_map crashes the XLA bundled with jax<0.5")
 def test_moe_local_dispatch_matches_global(run8):
     run8("""
 import jax, numpy as np
-from repro.core.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.models import registry, moe
 from repro.runtime import Trainer, TrainConfig
 from repro.data import make_pipeline
 from repro.configs.base import ShapeConfig
 
-mesh = make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
 shape = ShapeConfig("tiny", 64, 8, "train")
 losses = {}
 for mode in ["global", "local"]:
@@ -117,19 +115,17 @@ print("OK")
 """, timeout=1200)
 
 
-@pytest.mark.skipif(jax.__version_info__ < (0, 5, 0),
-                    reason="partial-auto shard_map crashes the XLA bundled with jax<0.5")
 def test_tp_reduce_16bit_matches(run8):
     run8("""
 import jax, numpy as np
-from repro.core.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.models import registry, layers
 from repro.core import lanes
 from repro.runtime import Trainer, TrainConfig
 from repro.data import make_pipeline
 from repro.configs.base import ShapeConfig
 
-mesh = make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
 shape = ShapeConfig("tiny", 64, 4, "train")
 losses = {}
 try:

@@ -1,0 +1,166 @@
+"""Compile the served Pallas kernels and the llama3.2-3b serving steps for a
+TPU v5e that is described, not attached.
+
+The TPU compiler ships with jax's TPU support, so these tests need no chip:
+they lower and compile for ``v5e:2x2`` and assert what only that compiler
+can tell — the kernels meet Mosaic's tiling rules at real widths
+(llama3.2-3b for attention, mamba2-2.7b for SSD), the steps keep the
+kernels (``tpu_custom_call``) and the decode step fits one chip's memory.
+Nothing runs, so nothing here says anything about results or times.
+
+The topology is described inside a module fixture (never at import): only
+one process may hold the TPU library, and every test worker imports every
+test file.  The persistent compilation cache is off around these compiles,
+because entries written for a described chip cannot be read back without
+one.
+"""
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+V5E_HBM_BYTES = 16 * 10**9
+
+# llama3.2-3b serving widths: 8 slots x 4096 rows, 8 KV heads of 128, 3
+# query heads per KV head
+SLOTS, ROWS, KVH, GROUP, HD = 8, 4096, 8, 3, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    with pytest.MonkeyPatch.context() as mp:
+        # keep the TPU compiler's logs out of the temp directory
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield desc
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def pallas_mode():
+    """Trace the model's ops on the kernel path (``auto`` resolves to
+    ``ref`` on this CPU host)."""
+    from repro.kernels import ops
+    prev = ops.get_mode()
+    ops.set_mode("pallas")
+    yield
+    ops.set_mode(prev)
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _abstract(sharding, tree):
+    return jax.tree.map(lambda x: _spec(sharding, x.shape, x.dtype), tree)
+
+
+def _compile(fn, *args, donate=()):
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    return compiled
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_flash_decode_compiles(one_chip, kv_dtype):
+    from repro.kernels import flash_decode as fd
+    s = lambda shape, dt: _spec(one_chip, shape, dt)
+    bkv = SLOTS * KVH
+    args = [s((bkv, GROUP, HD), jnp.bfloat16),
+            s((bkv, ROWS, HD), kv_dtype), s((bkv, ROWS, HD), kv_dtype),
+            s((bkv,), jnp.int32)]
+    if kv_dtype == "int8":
+        args += [s((bkv, ROWS), jnp.float32), s((bkv, ROWS), jnp.float32)]
+        _compile(lambda q, k, v, n, ks, vs: fd.flash_decode(
+            q, k, v, n, scales=(ks, vs)), *args)
+    else:
+        _compile(fd.flash_decode, *args)
+
+
+@pytest.mark.parametrize("chunk", [32, 512])
+def test_flash_prefill_chunk_compiles(one_chip, chunk):
+    from repro.kernels import flash_prefill_chunk as fpc
+    s = lambda shape, dt: _spec(one_chip, shape, dt)
+    _compile(fpc.flash_prefill_chunk,
+             s((KVH, GROUP, chunk, HD), jnp.bfloat16),
+             s((KVH, ROWS, HD), jnp.bfloat16),
+             s((KVH, ROWS, HD), jnp.bfloat16), s((KVH,), jnp.int32))
+
+
+def test_flash_attention_compiles(one_chip):
+    """Monolithic prefill of a ragged 300-token prompt (24 heads): queries
+    and keys pad to different block multiples, keys past 300 masked."""
+    from repro.kernels import ops
+    s = lambda shape: _spec(one_chip, shape, jnp.bfloat16)
+    _compile(lambda q, k, v: ops.attention(q, k, v, mode="pallas"),
+             s((24, 300, HD)), s((24, 300, HD)), s((24, 300, HD)))
+
+
+def test_ssd_compiles(one_chip):
+    """mamba2-2.7b: 80 heads of P=64, state N=128, chunk 256."""
+    from repro.kernels import ssd
+    s = lambda shape, dt: _spec(one_chip, shape, dt)
+    bh, seq, p, n = 80, 512, 64, 128
+    _compile(lambda x, la, b, c: ssd.ssd(x, la, b, c, chunk=256),
+             s((bh, seq, p), jnp.bfloat16), s((bh, seq), jnp.float32),
+             s((bh, seq, n), jnp.bfloat16), s((bh, seq, n), jnp.bfloat16))
+
+
+@pytest.fixture(scope="module")
+def llama(one_chip):
+    """llama3.2-3b at published widths: model, abstract params, abstract
+    bf16 arena of SLOTS x ROWS rows (all on the described chip)."""
+    from repro.models import registry
+    model = registry.build("llama3.2-3b").model
+    params = _abstract(one_chip, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0))))
+    cache = _abstract(one_chip, jax.eval_shape(
+        lambda: model.init_cache(SLOTS, ROWS, kv_format="bf16")))
+    return model, params, cache
+
+
+def test_llama_decode_step_fits_v5e(one_chip, llama, pallas_mode):
+    """The engine's served decode step (greedy twin), arena donated."""
+    from repro.runtime.serving import engine, sampling
+    model, params, cache = llama
+    vec = _spec(one_chip, (SLOTS,), jnp.int32)
+    samp = _abstract(one_chip, sampling.init_slot_state(SLOTS))
+    step = engine._compiled_decode_greedy(model, True)
+    compiled = step.lower(params, vec, cache, vec, vec, samp).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    resident = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.alias_size_in_bytes > 0          # the arena is donated
+    assert resident < V5E_HBM_BYTES, mem
+
+
+def test_llama_chunk_step_compiles(one_chip, llama, pallas_mode):
+    """The engine's served 512-token chunk-prefill step, arena donated."""
+    from repro.runtime.serving import engine
+    model, params, cache = llama
+    scalar = _spec(one_chip, (), jnp.int32)
+    step = engine._compiled_prefill_chunk(model, True)
+    compiled = step.lower(params, cache,
+                          _spec(one_chip, (1, 512), jnp.int32),
+                          scalar, scalar, scalar).compile()
+    assert "tpu_custom_call" in compiled.as_text()
