@@ -30,6 +30,8 @@ from typing import Any, Callable
 import jax
 from jax import lax
 
+from repro.core import spans
+
 
 class DispatchQueue:
     """Bounded async dispatch of a compiled step function.
@@ -44,28 +46,38 @@ class DispatchQueue:
     ``lambda out: out[-1]`` to track a never-donated output (the serving
     engine's host-readback token copy).  Any output of the step becomes
     ready exactly when the step completes, so backpressure is unchanged.
+
+    ``counts``: the dict whose ``"host_blocked_s"`` the backpressure
+    blocks add to (``spans.wait``, ``what="backpressure"``); the serving
+    engine passes its ``stats``.  Without one the queue keeps its own.
     """
 
     def __init__(self, step_fn: Callable, *, depth: int = 2,
-                 inflight_of: Callable[[Any], Any] = lambda out: out):
+                 inflight_of: Callable[[Any], Any] = lambda out: out,
+                 counts: dict | None = None):
         self.step_fn = step_fn
         self.depth = depth
         self._inflight_of = inflight_of
         self._inflight: collections.deque = collections.deque()
+        self.counts = counts if counts is not None else {spans.BLOCKED: 0.0}
+
+    def _block(self, value: Any) -> None:
+        with spans.wait("backpressure", self.counts):
+            jax.block_until_ready(value)
 
     def submit(self, state: Any, *args) -> Any:
         out = self.step_fn(state, *args)
         if self.depth == 0:
-            jax.block_until_ready(self._inflight_of(out))
+            self._block(self._inflight_of(out))
             return out
         self._inflight.append(self._inflight_of(out))
         while len(self._inflight) > self.depth:
-            jax.block_until_ready(self._inflight.popleft())
+            self._block(self._inflight.popleft())
         return out
 
     def drain(self) -> None:
         while self._inflight:
-            jax.block_until_ready(self._inflight.popleft())
+            self._block(self._inflight.popleft())
 
 
 def ideal_dispatcher(step_fn: Callable, num_steps: int, *, unroll: int = 1):

@@ -70,5 +70,6 @@ def conv2d(x: jax.Array, w: jax.Array, *, bh: int = 8, bw: int = 128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "parallel")),
+        name="conv2d",
         interpret=interpret,
     )(x, w)

@@ -70,6 +70,7 @@ def dotp(a: jax.Array, b: jax.Array, *, strip: int = DEFAULT_STRIP,
         scratch_shapes=[pltpu.VMEM((SUBLANES, LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
+        name="dotp",
         interpret=interpret,
     )(a, b)
     return out[0, 0]

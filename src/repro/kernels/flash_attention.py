@@ -121,5 +121,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)

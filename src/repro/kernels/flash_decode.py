@@ -152,5 +152,6 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
         out_shape=jax.ShapeDtypeStruct((bkv, g, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="flash_decode",
         interpret=interpret,
     )(*operands)

@@ -149,6 +149,7 @@ def flash_prefill_chunk(q: jax.Array, k: jax.Array, v: jax.Array,
         out_shape=jax.ShapeDtypeStruct((bkv, g * c, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="flash_prefill_chunk",
         interpret=interpret,
     )(*operands)
     return out.reshape(bkv, g, c, d)

@@ -69,5 +69,6 @@ def matmul(a: jax.Array, b: jax.Array, *, bm: int = DEFAULT_BM,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="matmul",
         interpret=interpret,
     )(a, b)

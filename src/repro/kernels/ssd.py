@@ -122,6 +122,7 @@ def ssd(x: jax.Array, log_a: jax.Array, B: jax.Array, C: jax.Array, *,
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="ssd",
         interpret=interpret,
     )(x, log_a[:, None, :], B, C, st0)
     return y, st

@@ -88,7 +88,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import kv_format as kv_format_mod
-from repro.core import masking
+from repro.core import masking, spans
 from repro.core.dispatch import DispatchQueue
 from repro.models.layers import PARKED_POS
 from repro.runtime.serving import chunking, sampling
@@ -645,11 +645,6 @@ class ServingEngine:
             self._verify = _compiled_verify(model, self.donate)
             self._verify_greedy = _compiled_verify_greedy(model, self.donate)
             self._verify_shapes: set = set()
-        # decode-state buffers are donated into each step, so the queue
-        # tracks a never-donated readback output (the sampled vector,
-        # out[-2] — out[-1] is the ok-flag readback) for backpressure
-        self._queue = DispatchQueue(self._submit_decode, depth=self.depth,
-                                    inflight_of=lambda out: out[-2])
         # readback copies of in-flight steps' tokens, with the slot→state
         # map seen at submit; per-slot admission generation guards against
         # crediting a stale in-flight token to a slot that was recycled
@@ -686,7 +681,17 @@ class ServingEngine:
                       "host_blocked_s": 0.0, "ttft_s": {},
                       "kv_format": self.kv_format,
                       "kv_row_bytes": self.kv_row_bytes,
-                      "arena_bytes": self.arena_bytes}
+                      "arena_bytes": self.arena_bytes,
+                      # engine steps, and PREFILLING slots summed once per
+                      # step after admission (slot-steps held by prefill)
+                      "steps": 0, "slot_steps_prefilling": 0}
+        # decode-state buffers are donated into each step, so the queue
+        # tracks a never-donated readback output (the sampled vector,
+        # out[-2] — out[-1] is the ok-flag readback) for backpressure; its
+        # blocks count into the engine's host_blocked_s
+        self._queue = DispatchQueue(self._submit_decode, depth=self.depth,
+                                    inflight_of=lambda out: out[-2],
+                                    counts=self.stats)
         if self._injector is not None:
             # live view of per-site fire counts (aliased, not copied)
             self.stats["faults"] = self._injector.fired
@@ -843,7 +848,8 @@ class ServingEngine:
     def _first_token(self, st: RequestState) -> None:
         if st.ttft_s is not None:
             return      # preemption recompute: keep the *first* first-token
-        st.ttft_s = self._clock() - st.submitted_at
+        st.first_token_at = self._clock()
+        st.ttft_s = st.first_token_at - st.submitted_at
         self.stats["ttft_s"][st.request.uid] = st.ttft_s
 
     # -- intake --------------------------------------------------------------
@@ -898,48 +904,54 @@ class ServingEngine:
 
     # -- admission (prefill + splice) ----------------------------------------
     def _admit(self) -> None:
-        for st in self.scheduler.schedule(tick=self._tick):
-            if st.slot is None:
-                # evicted again by an earlier admission's row reservation
-                # before we got to prefill it — it's back in the wait queue
-                continue
-            if st.slot in self._poisoned_slots:
-                self._scrub_slot(st.slot)
-            if st.status == Status.PREFILLING:
-                # chunked: park the slot's position pointer at the sentinel
-                # so in-flight decode steps cannot touch the slot — KV
-                # scatters for the row go out of bounds and are dropped,
-                # and recurrent-state writes (SSD state is not
-                # position-addressed) mask on pos < PARKED_POS inside the
-                # family's rows_scatter
-                self._pos = _park_slot_jit(self._pos, jnp.int32(st.slot),
-                                           jnp.int32(PARKED_POS))
-                continue
-            if st.status != Status.RUNNING:
-                continue
-            self._slot_gen[st.slot] += 1
-            req = st.request
-            extras = {k: jnp.asarray(v)[None] for k, v in
-                      (req.extras or {}).items()}
-            prompt = jnp.asarray(req.prompt)[None, :]
-            logits, one_cache = self._prefill(prompt, self._one_cache,
-                                              extras)
-            self.stats["prefills"] += 1
-            self._note_prefill_shape(("prefill", int(prompt.shape[1])))
-            self._cache = self._insert(self._cache, one_cache,
-                                       jnp.int32(st.slot))
-            if self.spec is not None:
-                # mirror the prompt into the draft arena (logits discarded)
-                # so both caches agree on rows [0, prompt_len) — the
-                # lockstep invariant every spec round relies on.  A
-                # preemption recompute re-runs both, so the caches can
-                # never drift apart.
-                _, draft_one = self._draft_prefill_fn(
-                    self._draft_params, prompt, self._draft_one_cache, {})
-                self._draft_cache = self._insert(self._draft_cache,
-                                                 draft_one,
-                                                 jnp.int32(st.slot))
-            self._activate_slot(st, logits)
+        with spans.span("serving.admit"):
+            for st in self.scheduler.schedule(tick=self._tick):
+                if st.admitted_at is None:
+                    # first departure from WAITING; a preemption recompute
+                    # keeps it, as it keeps ttft_s
+                    st.admitted_at = self._clock()
+                if st.slot is None:
+                    # evicted again by an earlier admission's row
+                    # reservation before we got to prefill it — it's back
+                    # in the wait queue
+                    continue
+                if st.slot in self._poisoned_slots:
+                    self._scrub_slot(st.slot)
+                if st.status == Status.PREFILLING:
+                    # chunked: park the slot's position pointer at the sentinel
+                    # so in-flight decode steps cannot touch the slot — KV
+                    # scatters for the row go out of bounds and are dropped,
+                    # and recurrent-state writes (SSD state is not
+                    # position-addressed) mask on pos < PARKED_POS inside the
+                    # family's rows_scatter
+                    self._pos = _park_slot_jit(self._pos, jnp.int32(st.slot),
+                                               jnp.int32(PARKED_POS))
+                    continue
+                if st.status != Status.RUNNING:
+                    continue
+                self._slot_gen[st.slot] += 1
+                req = st.request
+                extras = {k: jnp.asarray(v)[None] for k, v in
+                          (req.extras or {}).items()}
+                prompt = jnp.asarray(req.prompt)[None, :]
+                logits, one_cache = self._prefill(prompt, self._one_cache,
+                                                  extras)
+                self.stats["prefills"] += 1
+                self._note_prefill_shape(("prefill", int(prompt.shape[1])))
+                self._cache = self._insert(self._cache, one_cache,
+                                           jnp.int32(st.slot))
+                if self.spec is not None:
+                    # mirror the prompt into the draft arena (logits discarded)
+                    # so both caches agree on rows [0, prompt_len) — the
+                    # lockstep invariant every spec round relies on.  A
+                    # preemption recompute re-runs both, so the caches can
+                    # never drift apart.
+                    _, draft_one = self._draft_prefill_fn(
+                        self._draft_params, prompt, self._draft_one_cache, {})
+                    self._draft_cache = self._insert(self._draft_cache,
+                                                     draft_one,
+                                                     jnp.int32(st.slot))
+                self._activate_slot(st, logits)
 
     def _activate_slot(self, st: RequestState, logits) -> None:
         """Sample the prompt's first token off ``logits`` (1, V) and put
@@ -965,7 +977,9 @@ class ServingEngine:
             token0 = jnp.argmax(logits[0], -1).astype(jnp.int32)
         else:
             token0 = sampling.sample_first(logits, seed, pos0, sp)
-        if not bool(ok0):
+        with spans.wait("first_token", self.stats):
+            ok = bool(ok0)
+        if not ok:
             self.stats["quarantined"] += 1
             self._step_faulted = True
             self._depart(st, Status.FAILED, "nan-logits")
@@ -981,9 +995,8 @@ class ServingEngine:
                                          jnp.int32(st.share_len))
         # reading token0 syncs the host on this prefill only; in-flight
         # decode steps keep running on the device
-        t0 = time.perf_counter()
-        tok = int(token0)
-        self.stats["host_blocked_s"] += time.perf_counter() - t0
+        with spans.wait("first_token", self.stats):
+            tok = int(token0)
         self._first_token(st)
         self._tokens, self._pos, self._active = self._set_slot(
             self._tokens, self._pos, self._active, jnp.int32(slot),
@@ -1015,57 +1028,58 @@ class ServingEngine:
         arrivals cannot starve a long prompt's ingestion."""
         if self.prefill_chunks is None:
             return
-        self._prefill_tick += 1
-        spent = 0
-        budget = self._effective_prefill_budget()
-        faulted: set = set()    # slots whose ingest dispatch was dropped
-        #                         this step (chunk fault site): they stall
-        #                         one full step, cursor unmoved
+        with spans.span("serving.prefill"):
+            self._prefill_tick += 1
+            spent = 0
+            budget = self._effective_prefill_budget()
+            faulted: set = set()    # slots whose ingest dispatch was dropped
+            #                         this step (chunk fault site): they stall
+            #                         one full step, cursor unmoved
 
-        def prefilling():
-            return [st for st in self.scheduler.running.values()
-                    if st.status == Status.PREFILLING
-                    and st.slot is not None]
+            def prefilling():
+                return [st for st in self.scheduler.running.values()
+                        if st.status == Status.PREFILLING
+                        and st.slot is not None]
 
-        if self._prefill_tick % 2:
-            states = prefilling()
-            if not states:
-                return
-            oldest = min(states, key=lambda s: s.seq)
-            # the oldest PREFILLING slot never defers (deferral waits on a
-            # strictly older pure prefill), so this can only fork
-            self._maybe_fork(oldest)
-            size = oldest.chunk_plan[oldest.chunk_idx]
-            if self._prefill_one_chunk(oldest, size):
-                spent += size
-            else:
-                faulted.add(oldest.slot)
-        while True:
-            states = sorted(prefilling(),
-                            key=lambda s: (s.prefill_pos, s.seq))
-            if not states:
-                return
-            progressed = False
-            for st in states:
-                if st.status != Status.PREFILLING or st.slot is None:
-                    continue        # departed via an earlier activation
-                if st.slot in faulted:
-                    continue        # dropped dispatch: stalled this step
-                if self._maybe_fork(st):
-                    continue        # deferred: an older donor is still
-                    #                 publishing this slot's prefix
-                size = st.chunk_plan[st.chunk_idx]
-                # always ingest at least one chunk per step (progress
-                # guarantee), then stay within the budget
-                if spent and spent + size > budget:
+            if self._prefill_tick % 2:
+                states = prefilling()
+                if not states:
                     return
-                if not self._prefill_one_chunk(st, size):
-                    faulted.add(st.slot)
-                    continue
-                spent += size
-                progressed = True
-            if not progressed:
-                return              # everything left is deferred/faulted
+                oldest = min(states, key=lambda s: s.seq)
+                # the oldest PREFILLING slot never defers (deferral waits on a
+                # strictly older pure prefill), so this can only fork
+                self._maybe_fork(oldest)
+                size = oldest.chunk_plan[oldest.chunk_idx]
+                if self._prefill_one_chunk(oldest, size):
+                    spent += size
+                else:
+                    faulted.add(oldest.slot)
+            while True:
+                states = sorted(prefilling(),
+                                key=lambda s: (s.prefill_pos, s.seq))
+                if not states:
+                    return
+                progressed = False
+                for st in states:
+                    if st.status != Status.PREFILLING or st.slot is None:
+                        continue        # departed via an earlier activation
+                    if st.slot in faulted:
+                        continue        # dropped dispatch: stalled this step
+                    if self._maybe_fork(st):
+                        continue        # deferred: an older donor is still
+                        #                 publishing this slot's prefix
+                    size = st.chunk_plan[st.chunk_idx]
+                    # always ingest at least one chunk per step (progress
+                    # guarantee), then stay within the budget
+                    if spent and spent + size > budget:
+                        return
+                    if not self._prefill_one_chunk(st, size):
+                        faulted.add(st.slot)
+                        continue
+                    spent += size
+                    progressed = True
+                if not progressed:
+                    return              # everything left is deferred/faulted
 
     def _maybe_fork(self, st: RequestState) -> bool:
         """At a slot's first ingestion under prefix sharing: try to remap
@@ -1182,48 +1196,51 @@ class ServingEngine:
         req = st.request
         plen = st.prompt_len
         start = st.prefill_pos
-        chunk = np.zeros((size,), np.int32)
         real = min(size, plen - start)
-        chunk[:real] = req.prompt[start:start + real]
-        is_last = st.chunk_idx == len(st.chunk_plan) - 1
-        # index of the chunk's last *real* token: size - 1 except on a
-        # padded final chunk.  Recurrent families read it as the chunk's
-        # valid length (pad positions are masked out of the SSD state
-        # recurrence); the final chunk's logits are taken there.
-        last_idx = real - 1
-        if self.prefix_sharing:
-            src = st.share_src if st.share_src is not None else st.slot
-            logits, self._cache = self._chunk_fn(
-                self.params, self._cache, jnp.asarray(chunk)[None, :],
-                jnp.int32(st.slot), jnp.int32(start), jnp.int32(last_idx),
-                jnp.int32(src), jnp.int32(st.share_len))
-        else:
-            logits, self._cache = self._chunk_fn(
-                self.params, self._cache, jnp.asarray(chunk)[None, :],
-                jnp.int32(st.slot), jnp.int32(start), jnp.int32(last_idx))
-        if self.spec is not None:
-            # lockstep draft ingestion: the identical chunk goes into the
-            # draft arena (same slot, same rows; logits discarded), so a
-            # slot finishing prefill has BOTH caches live on [0, prompt_len)
-            _, self._draft_cache = self._draft_chunk_fn(
-                self._draft_params, self._draft_cache,
-                jnp.asarray(chunk)[None, :], jnp.int32(st.slot),
-                jnp.int32(start), jnp.int32(last_idx))
-        self.stats["prefill_chunks"] += 1
-        self.stats["prefill_rows"] += size
-        self._note_prefill_shape(("chunk", size))
-        st.prefill_pos = start + size
-        st.chunk_idx += 1
-        if self.prefix_sharing and st.share_src is None:
-            self._register_prefix(st)
-        if not is_last:
+        with spans.span("serving.chunk", uid=req.uid, size=size,
+                        valid=real):
+            chunk = np.zeros((size,), np.int32)
+            chunk[:real] = req.prompt[start:start + real]
+            is_last = st.chunk_idx == len(st.chunk_plan) - 1
+            # index of the chunk's last *real* token: size - 1 except on a
+            # padded final chunk.  Recurrent families read it as the chunk's
+            # valid length (pad positions are masked out of the SSD state
+            # recurrence); the final chunk's logits are taken there.
+            last_idx = real - 1
+            if self.prefix_sharing:
+                src = st.share_src if st.share_src is not None else st.slot
+                logits, self._cache = self._chunk_fn(
+                    self.params, self._cache, jnp.asarray(chunk)[None, :],
+                    jnp.int32(st.slot), jnp.int32(start), jnp.int32(last_idx),
+                    jnp.int32(src), jnp.int32(st.share_len))
+            else:
+                logits, self._cache = self._chunk_fn(
+                    self.params, self._cache, jnp.asarray(chunk)[None, :],
+                    jnp.int32(st.slot), jnp.int32(start), jnp.int32(last_idx))
+            if self.spec is not None:
+                # lockstep draft ingestion: the identical chunk goes into
+                # the draft arena (same slot, same rows; logits discarded),
+                # so a slot finishing prefill has BOTH caches live on
+                # [0, prompt_len)
+                _, self._draft_cache = self._draft_chunk_fn(
+                    self._draft_params, self._draft_cache,
+                    jnp.asarray(chunk)[None, :], jnp.int32(st.slot),
+                    jnp.int32(start), jnp.int32(last_idx))
+            self.stats["prefill_chunks"] += 1
+            self.stats["prefill_rows"] += size
+            self._note_prefill_shape(("chunk", size))
+            st.prefill_pos = start + size
+            st.chunk_idx += 1
+            if self.prefix_sharing and st.share_src is None:
+                self._register_prefix(st)
+            if not is_last:
+                return True
+            # final chunk: sample the first token and join the decode batch
+            self.scheduler.finish_prefill(st.slot)
+            # steps submitted mid-prefill are stale for this slot: drop them
+            self._slot_gen[st.slot] += 1
+            self._activate_slot(st, logits)
             return True
-        # final chunk: sample the first token and join the decode batch
-        self.scheduler.finish_prefill(st.slot)
-        # steps submitted mid-prefill are stale for this slot: drop them
-        self._slot_gen[st.slot] += 1
-        self._activate_slot(st, logits)
-        return True
 
     # -- speculative rounds ---------------------------------------------------
     def _spec_round(self) -> None:
@@ -1256,82 +1273,81 @@ class ServingEngine:
         if not running:
             return
         k = self.spec.k
-        tok0 = np.zeros((self.max_slots,), np.int32)
-        pos0 = np.full((self.max_slots,), PARKED_POS, np.int32)
-        for st in running:
-            # the slot's current (committed, not yet cached) token and the
-            # arena row it will occupy; non-RUNNING slots park at the
-            # sentinel so every draft scatter for them is dropped —
-            # PREFILLING slots' freshly-ingested rows stay untouched
-            tok0[st.slot] = st.generated[-1]
-            pos0[st.slot] = (st.prompt_len + self.prefix_extra
-                             + len(st.generated) - 1)
-        all_greedy = all(st.request.sampling.is_greedy for st in running)
-        draft_fn = (self._draft_propose_greedy if all_greedy
-                    else self._draft_propose)
-        toks = jnp.asarray(tok0)
-        base = jnp.asarray(pos0)
-        proposals = []
-        for j in range(k):
-            toks, self._draft_cache = draft_fn(
-                self._draft_params, toks, self._draft_cache, base + j,
-                self._samp)
-            proposals.append(toks)
-        self.stats["spec_draft_steps"] += k
-        # one host sync for the round's proposals (they shape the verify
-        # chunks); the per-slot verify calls then launch back-to-back and
-        # their draw vectors are read after all are in flight
-        t0 = time.perf_counter()
-        props = np.stack([np.asarray(p) for p in proposals])     # (k, B)
-        self.stats["host_blocked_s"] += time.perf_counter() - t0
-        if self._injector is not None and self._injector.fire("draft"):
-            # corrupt the round's proposals host-side.  Self-correcting by
-            # construction: acceptance compares against the target's own
-            # draws, so the committed stream is unchanged — only the
-            # acceptance rate collapses for this round.
-            props = (props + 1) % self.cfg.vocab
-            self._step_faulted = True
-        reads = []
-        for st in running:
-            slot = st.slot
-            chunk = np.concatenate(
-                [[tok0[slot]], props[:k - 1, slot]]).astype(np.int32)
-            vfn = (self._verify_greedy if st.request.sampling.is_greedy
-                   else self._verify)
-            draws, okv, self._cache = vfn(
-                self.params, self._cache, jnp.asarray(chunk)[None, :],
-                jnp.int32(slot), jnp.int32(pos0[slot]), self._samp)
-            reads.append((st, slot, draws, okv))
-        self._verify_shapes.add(k)
-        self.stats["spec_verify_calls"] += len(reads)
-        self.stats["spec_verify_compiles"] = len(self._verify_shapes)
-        outcomes = []
-        for st, slot, draws, okv in reads:
-            if st.status != Status.RUNNING or st.slot != slot:
-                continue    # preempted by an earlier commit this round:
-                #             its generated stream was rewound, recompute
-                #             replays it — this round's draws are void
-            t0 = time.perf_counter()
-            draws = np.asarray(draws)
-            ok = bool(np.asarray(okv))
-            self.stats["host_blocked_s"] += time.perf_counter() - t0
-            if not ok:
-                # verify logits went non-finite: quarantine the slot, no
-                # token of this round commits (survivors are untouched —
-                # the NaN lives in the victim's own arena region)
-                self.stats["quarantined"] += 1
+        with spans.span("serving.spec_round", k=k):
+            tok0 = np.zeros((self.max_slots,), np.int32)
+            pos0 = np.full((self.max_slots,), PARKED_POS, np.int32)
+            for st in running:
+                # the slot's current (committed, not yet cached) token and the
+                # arena row it will occupy; non-RUNNING slots park at the
+                # sentinel so every draft scatter for them is dropped —
+                # PREFILLING slots' freshly-ingested rows stay untouched
+                tok0[st.slot] = st.generated[-1]
+                pos0[st.slot] = (st.prompt_len + self.prefix_extra
+                                 + len(st.generated) - 1)
+            all_greedy = all(st.request.sampling.is_greedy for st in running)
+            draft_fn = (self._draft_propose_greedy if all_greedy
+                        else self._draft_propose)
+            toks = jnp.asarray(tok0)
+            base = jnp.asarray(pos0)
+            proposals = []
+            for j in range(k):
+                toks, self._draft_cache = draft_fn(
+                    self._draft_params, toks, self._draft_cache, base + j,
+                    self._samp)
+                proposals.append(toks)
+            self.stats["spec_draft_steps"] += k
+            # one host sync for the round's proposals (they shape the verify
+            # chunks); the per-slot verify calls then launch back-to-back and
+            # their draw vectors are read after all are in flight
+            with spans.wait("spec_readback", self.stats):
+                props = np.stack([np.asarray(p) for p in proposals])  # (k, B)
+            if self._injector is not None and self._injector.fire("draft"):
+                # corrupt the round's proposals host-side.  Self-correcting by
+                # construction: acceptance compares against the target's own
+                # draws, so the committed stream is unchanged — only the
+                # acceptance rate collapses for this round.
+                props = (props + 1) % self.cfg.vocab
                 self._step_faulted = True
-                self._depart(st, Status.FAILED, "nan-logits")
-                continue
-            a, committed = sampling.accept_tokens(props[:, slot], draws)
-            n, _ = self.scheduler.on_tokens(slot, committed)
-            self.stats["tokens_out"] += n
-            outcomes.append((st.request.uid, a, k))
-        self.spec.observe_round(outcomes)
-        self.stats["spec_rounds"] += 1
-        self.stats["decode_steps"] += 1
-        if not all_greedy:
-            self.stats["sampled_steps"] += 1
+            reads = []
+            for st in running:
+                slot = st.slot
+                chunk = np.concatenate(
+                    [[tok0[slot]], props[:k - 1, slot]]).astype(np.int32)
+                vfn = (self._verify_greedy if st.request.sampling.is_greedy
+                       else self._verify)
+                draws, okv, self._cache = vfn(
+                    self.params, self._cache, jnp.asarray(chunk)[None, :],
+                    jnp.int32(slot), jnp.int32(pos0[slot]), self._samp)
+                reads.append((st, slot, draws, okv))
+            self._verify_shapes.add(k)
+            self.stats["spec_verify_calls"] += len(reads)
+            self.stats["spec_verify_compiles"] = len(self._verify_shapes)
+            outcomes = []
+            for st, slot, draws, okv in reads:
+                if st.status != Status.RUNNING or st.slot != slot:
+                    continue    # preempted by an earlier commit this round:
+                    #             its generated stream was rewound, recompute
+                    #             replays it — this round's draws are void
+                with spans.wait("spec_readback", self.stats):
+                    draws = np.asarray(draws)
+                    ok = bool(np.asarray(okv))
+                if not ok:
+                    # verify logits went non-finite: quarantine the slot, no
+                    # token of this round commits (survivors are untouched —
+                    # the NaN lives in the victim's own arena region)
+                    self.stats["quarantined"] += 1
+                    self._step_faulted = True
+                    self._depart(st, Status.FAILED, "nan-logits")
+                    continue
+                a, committed = sampling.accept_tokens(props[:, slot], draws)
+                n, _ = self.scheduler.on_tokens(slot, committed)
+                self.stats["tokens_out"] += n
+                outcomes.append((st.request.uid, a, k))
+            self.spec.observe_round(outcomes)
+            self.stats["spec_rounds"] += 1
+            self.stats["decode_steps"] += 1
+            if not all_greedy:
+                self.stats["sampled_steps"] += 1
 
     # -- the continuous-batching loop ----------------------------------------
     def step(self) -> None:
@@ -1341,102 +1357,113 @@ class ServingEngine:
         synchronous draft-propose/verify/commit round instead of submitting
         a decode step."""
         self._tick += 1
-        self._drain_pending(limit=self.depth)
-        self._expire_deadlines()
-        self._observe_health()
-        self._admit()
-        self._advance_prefill()
-        running = [st for st in self.scheduler.running.values()
-                   if st.status == Status.RUNNING]
-        if not running:
-            return
-        inj = self._injector
-        if inj is not None and inj.fire("decode"):
-            # dropped dispatch: the whole decode step / spec round stalls
-            # one engine step.  Positions don't advance, so no slot's
-            # stream can diverge — the fault costs latency, never tokens.
-            self._step_faulted = True
-            return
-        if inj is not None and inj.fire("logits"):
-            self._poison_slot(running)
-        if self.spec is not None \
-                and self._health_state < HealthState.DEGRADED:
-            if self._pending:
-                # mode transition (queue decode -> spec rounds, i.e. the
-                # ladder just recovered): retire every in-flight queue
-                # step first so a committed token can't be re-credited
-                self._queue.drain()
-                self._drain_pending(limit=0)
-            self._spec_round()
-            self._spec_resync = True
-            return
-        if self._spec_resync:
-            # mode transition (spec rounds -> queue decode, the ladder
-            # degraded): the device slot vectors lag the spec commits —
-            # resync tokens/pos from host state for every RUNNING slot
-            for st in running:
-                self._tokens, self._pos, self._active = self._set_slot(
-                    self._tokens, self._pos, self._active,
-                    jnp.int32(st.slot), jnp.int32(st.generated[-1]),
-                    jnp.int32(st.prompt_len + self.prefix_extra
-                              + len(st.generated) - 1))
-            self._spec_resync = False
-        # executable choice: only a step with a sampled RUNNING slot pays
-        # the sampling transform; pure-greedy steps run the argmax twin
-        self._use_sampling = any(not st.request.sampling.is_greedy
-                                 for st in running)
-        state = (self._tokens, self._cache, self._pos, self._active,
-                 self._samp)
-        if self.prefix_sharing:
-            state = state + (self._share,)
-        out = self._queue.submit(state)
-        # rebind to the outputs: the submitted buffers were donated and are
-        # dead from here on
-        if self.prefix_sharing:
-            (self._tokens, self._cache, self._pos, self._active, self._samp,
-             self._share, read, okv) = out
-        else:
-            (self._tokens, self._cache, self._pos, self._active, self._samp,
-             read, okv) = out
-        self.stats["decode_steps"] += 1
-        snapshot = {slot: (st, self._slot_gen[slot])
-                    for slot, st in self.scheduler.running.items()}
-        self._pending.append((read, okv, snapshot))
+        self.stats["steps"] += 1
+        with spans.span("serving.step", tick=self._tick):
+            self._drain_pending(limit=self.depth)
+            self._expire_deadlines()
+            self._observe_health()
+            self._admit()
+            self.stats["slot_steps_prefilling"] += sum(
+                st.status == Status.PREFILLING
+                for st in self.scheduler.running.values())
+            self._advance_prefill()
+            running = [st for st in self.scheduler.running.values()
+                       if st.status == Status.RUNNING]
+            if not running:
+                return
+            inj = self._injector
+            if inj is not None and inj.fire("decode"):
+                # dropped dispatch: the whole decode step / spec round stalls
+                # one engine step.  Positions don't advance, so no slot's
+                # stream can diverge — the fault costs latency, never tokens.
+                self._step_faulted = True
+                return
+            if inj is not None and inj.fire("logits"):
+                self._poison_slot(running)
+            if self.spec is not None \
+                    and self._health_state < HealthState.DEGRADED:
+                if self._pending:
+                    # mode transition (queue decode -> spec rounds, i.e. the
+                    # ladder just recovered): retire every in-flight queue
+                    # step first so a committed token can't be re-credited
+                    self._queue.drain()
+                    self._drain_pending(limit=0)
+                self._spec_round()
+                self._spec_resync = True
+                return
+            if self._spec_resync:
+                # mode transition (spec rounds -> queue decode, the ladder
+                # degraded): the device slot vectors lag the spec commits —
+                # resync tokens/pos from host state for every RUNNING slot
+                for st in running:
+                    self._tokens, self._pos, self._active = self._set_slot(
+                        self._tokens, self._pos, self._active,
+                        jnp.int32(st.slot), jnp.int32(st.generated[-1]),
+                        jnp.int32(st.prompt_len + self.prefix_extra
+                                  + len(st.generated) - 1))
+                self._spec_resync = False
+            # executable choice: only a step with a sampled RUNNING slot
+            # pays the sampling transform; pure-greedy steps run the argmax
+            # twin
+            self._use_sampling = any(not st.request.sampling.is_greedy
+                                     for st in running)
+            state = (self._tokens, self._cache, self._pos, self._active,
+                     self._samp)
+            if self.prefix_sharing:
+                state = state + (self._share,)
+            with spans.span("serving.decode", slots=len(running)):
+                out = self._queue.submit(state)
+            # rebind to the outputs: the submitted buffers were donated and
+            # are dead from here on
+            if self.prefix_sharing:
+                (self._tokens, self._cache, self._pos, self._active,
+                 self._samp, self._share, read, okv) = out
+            else:
+                (self._tokens, self._cache, self._pos, self._active,
+                 self._samp, read, okv) = out
+            self.stats["decode_steps"] += 1
+            snapshot = {slot: (st, self._slot_gen[slot])
+                        for slot, st in self.scheduler.running.items()}
+            self._pending.append((read, okv, snapshot))
 
     def _drain_pending(self, *, limit: int) -> None:
         """Process token outputs older than ``limit`` steps (blocking only
         on steps the queue has already forced to completion)."""
-        while len(self._pending) > limit:
-            tokens, okv, snapshot = self._pending.popleft()
-            t0 = time.perf_counter()
-            host_tokens = np.asarray(tokens)
-            host_ok = np.asarray(okv)
-            self.stats["host_blocked_s"] += time.perf_counter() - t0
-            for slot, (st, gen) in snapshot.items():
-                # stale entries: the request left this slot (finished or
-                # preempted) after the step was submitted, was still
-                # prefilling when it was submitted (gen bumped on
-                # activation), or the slot was recycled to a newer admission
-                if (st.status != Status.RUNNING or st.slot != slot
-                        or gen != self._slot_gen[slot]):
-                    continue
-                if not host_ok[slot]:
-                    # slot quarantine: non-finite logits.  The first
-                    # poisoned entry departs the slot FAILED before any
-                    # poisoned token commits (FIFO drain), and the later
-                    # in-flight entries for it die on the status guard
-                    # above.  Co-resident slots are untouched: the NaN
-                    # lives in the victim's own arena region, and the
-                    # flash kernels mask dead rows with a select, so it
-                    # cannot leak into another slot's softmax.
-                    self.stats["quarantined"] += 1
-                    self._step_faulted = True
-                    self._depart(st, Status.FAILED, "nan-logits")
-                    continue
-                self.stats["tokens_out"] += 1
-                deps = self.scheduler.on_token(slot, int(host_tokens[slot]))
-                for dslot, _ in deps:
-                    self._active = self._active.at[dslot].set(0)
+        if len(self._pending) <= limit:
+            return
+        with spans.span("serving.retire"):
+            while len(self._pending) > limit:
+                tokens, okv, snapshot = self._pending.popleft()
+                with spans.wait("readback", self.stats):
+                    host_tokens = np.asarray(tokens)
+                    host_ok = np.asarray(okv)
+                for slot, (st, gen) in snapshot.items():
+                    # stale entries: the request left this slot (finished
+                    # or preempted) after the step was submitted, was still
+                    # prefilling when it was submitted (gen bumped on
+                    # activation), or the slot was recycled to a newer
+                    # admission
+                    if (st.status != Status.RUNNING or st.slot != slot
+                            or gen != self._slot_gen[slot]):
+                        continue
+                    if not host_ok[slot]:
+                        # slot quarantine: non-finite logits.  The first
+                        # poisoned entry departs the slot FAILED before any
+                        # poisoned token commits (FIFO drain), and the later
+                        # in-flight entries for it die on the status guard
+                        # above.  Co-resident slots are untouched: the NaN
+                        # lives in the victim's own arena region, and the
+                        # flash kernels mask dead rows with a select, so it
+                        # cannot leak into another slot's softmax.
+                        self.stats["quarantined"] += 1
+                        self._step_faulted = True
+                        self._depart(st, Status.FAILED, "nan-logits")
+                        continue
+                    self.stats["tokens_out"] += 1
+                    deps = self.scheduler.on_token(slot,
+                                                   int(host_tokens[slot]))
+                    for dslot, _ in deps:
+                        self._active = self._active.at[dslot].set(0)
 
     def evacuate(self) -> list:
         """Remove every non-terminal request from service for migration and

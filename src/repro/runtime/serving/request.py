@@ -111,6 +111,10 @@ class RequestState:
 
     # service-time bookkeeping (engine-owned)
     submitted_at: Optional[float] = None  # engine clock at engine.submit
+    # engine clock when the request first left WAITING, and at its first
+    # sampled token; like ttft_s, a preemption recompute keeps both
+    admitted_at: Optional[float] = None
+    first_token_at: Optional[float] = None
     ttft_s: Optional[float] = None        # submit -> first sampled token
     deadline_at: Optional[float] = None   # engine clock; None = no deadline
 

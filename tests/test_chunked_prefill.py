@@ -587,10 +587,13 @@ def test_first_token_time_survives_preemption_recompute(tiny_model):
                             max_new_tokens=4))
     eng._first_token(st)
     first = st.ttft_s
+    at = st.first_token_at
     assert first is not None and eng.stats["ttft_s"]["r"] == first
+    assert first == at - st.submitted_at
     _time.sleep(0.01)
     eng._first_token(st)                        # recompute after preemption
     assert st.ttft_s == first                   # not overwritten
+    assert st.first_token_at == at
     assert eng.stats["ttft_s"]["r"] == first
 
 

@@ -82,3 +82,18 @@ def test_ideal_dispatcher_matches_loop():
     for _ in range(6):
         want = step(want)
     assert int(got) == int(want)
+
+
+def test_blocks_count_into_host_blocked_s():
+    """Every backpressure block is timed into ``counts``: the queue's own
+    dict, or the one its owner passes (the serving engine's stats)."""
+    step, _ = _counted_step()
+    q = dispatch.DispatchQueue(step, depth=0)
+    q.submit(jnp.int32(0))
+    assert q.counts["host_blocked_s"] > 0
+    shared = {"host_blocked_s": 1.0}
+    q = dispatch.DispatchQueue(step, depth=1, counts=shared)
+    for _ in range(3):
+        q.submit(jnp.int32(0))
+    q.drain()
+    assert q.counts is shared and shared["host_blocked_s"] > 1.0
