@@ -244,10 +244,17 @@ def ref_mode():
 
 
 def compare_logits(bundle, ref_model, params, eng) -> None:
-    """One decode step on the served arena, kernel path vs ``ref`` path."""
+    """One decode step on the served arena, kernel path vs ``ref`` path.
+
+    Every slot decodes at the end of a served request's length (prompt +
+    generated), so it attends that many arena rows over several strips:
+    the engine's own positions are parked once its requests finish, and a
+    parked slot attends no arena rows."""
     import jax
     import jax.numpy as jnp
-    args = (params, eng._tokens, eng._cache, eng._pos)
+    pos = jnp.asarray([PROMPT_LENS[i % len(PROMPT_LENS)] + GEN - 1
+                       for i in range(eng.max_slots)], jnp.int32)
+    args = (params, eng._tokens, eng._cache, pos)
     got = jax.jit(lambda *a: bundle.model.decode_step(*a)[0])(*args)
     with ref_mode():
         want = jax.jit(lambda *a: ref_model.decode_step(*a)[0])(*args)

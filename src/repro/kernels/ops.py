@@ -12,7 +12,8 @@ Every op has three executable paths:
 ``set_mode()`` pins a path; ``auto`` picks pallas on TPU backends and ref
 elsewhere (this CPU container always takes ref unless a test asks for
 interpret).  Non-aligned shapes are zero-padded here — the RVV tail —
-so the kernels stay branch-free.
+so the kernels stay branch-free; ``flash_decode`` alone masks its own
+tail strip, since it reads the KV arena in place.
 """
 from __future__ import annotations
 
@@ -315,58 +316,79 @@ def _flash_decode_ref(q, k, v, *, lengths, window, scale, bk,
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, *,
                  lengths: Optional[jax.Array] = None,
                  window: Optional[int] = None,
-                 scale: Optional[float] = None, bk: int = 512,
+                 scale: Optional[float] = None, bk: int = _fd.DEFAULT_BK,
                  k_scale: Optional[jax.Array] = None,
                  v_scale: Optional[jax.Array] = None,
+                 layer: Optional[jax.Array] = None,
+                 new_row: Optional[tuple] = None,
                  mode: Optional[Mode] = None) -> jax.Array:
     """One-token decode attention with per-sequence length masking.
 
     q: (B, H, hd) — the current token's queries; k/v: (B, S, KVH, hd) — the
-    (padded) KV cache; lengths: (B,) int32 count of live KV entries per
-    sequence (``None`` = all S live, e.g. enc-dec cross-attention).
-    Returns (B, H, hd).  GQA is handled here: H is grouped onto KVH so each
-    KV head is read once for its H/KVH query heads.
+    KV cache, or with ``layer`` (int32 scalar) the stacked (L, B, S, KVH,
+    hd) arena, read at that layer in place; lengths: (B,) int32 count of
+    live KV rows per sequence (``None`` = all S live, e.g. enc-dec
+    cross-attention).  Returns (B, H, hd).  GQA: consecutive H/KVH query
+    heads share a KV head, and each KV row is read once for all of them.
 
-    ``k_scale``/``v_scale``: optional (B, S, KVH) per-row dequant scales
-    for a quantized cache (core/kv_format.py); dequant fuses into the
-    inner loop — the arena is never widened in memory.
+    ``new_row``: the decode step's new token, ``(k_row, v_row)`` of (B, KVH,
+    hd) in the cache dtype, plus ``(k_scale, v_scale)`` of (B, KVH) for a
+    scaled format — the tuple :func:`repro.models.layers.
+    attention_decode_rows` emits.  It sits at key position ``lengths``,
+    after the arena rows [0, lengths), and is never written to the arena
+    here.
+
+    ``k_scale``/``v_scale``: optional (B, S, KVH) — or stacked (L, B, S,
+    KVH) — per-row dequant scales for a quantized cache
+    (core/kv_format.py); dequant fuses into the inner loop — the arena is
+    never widened in memory.
     """
     b, h, hd = q.shape
-    _, s, kvh, _ = k.shape
+    s, kvh = k.shape[-3], k.shape[-2]
     if h % kvh:
         raise ValueError(f"n_heads={h} not divisible by kv_heads={kvh}")
-    g = h // kvh
-    qg = q.reshape(b, kvh, g, hd)
     if lengths is None:
         lengths = jnp.full((b,), s, jnp.int32)
     lengths = lengths.astype(jnp.int32)
     mode = mode or _resolved()
     if mode == "ref":
-        out = _flash_decode_ref(qg, k, v, lengths=lengths, window=window,
+        # the oracle attends a layer view with the new row written in
+        if layer is not None:
+            k, v = k[layer], v[layer]
+            if k_scale is not None:
+                k_scale, v_scale = k_scale[layer], v_scale[layer]
+        if new_row is not None:
+            bidx = jnp.arange(b)
+            k = k.at[bidx, lengths].set(new_row[0])
+            v = v.at[bidx, lengths].set(new_row[1])
+            if k_scale is not None:
+                k_scale = k_scale.at[bidx, lengths].set(new_row[2])
+                v_scale = v_scale.at[bidx, lengths].set(new_row[3])
+            lengths = lengths + 1
+        out = _flash_decode_ref(q.reshape(b, kvh, h // kvh, hd), k, v,
+                                lengths=lengths, window=window,
                                 scale=scale, bk=bk,
                                 k_scale=k_scale, v_scale=v_scale)
         return out.reshape(b, h, hd)
-    bk_ = min(bk, s)
-    kp = _pad_to(k, bk_, 1)
-    vp = _pad_to(v, bk_, 1)
-    # fold (B, KVH) into the kernel grid axis; padded keys sit at positions
-    # >= every length, so the kernel's tail mask drops them
-    kf = jnp.moveaxis(kp, 2, 1).reshape(b * kvh, kp.shape[1], hd)
-    vf = jnp.moveaxis(vp, 2, 1).reshape(b * kvh, vp.shape[1], hd)
-    qf = qg.reshape(b * kvh, g, hd)
-    lf = jnp.repeat(lengths, kvh)
-    scales = None
-    if k_scale is not None:
-        # scales fold exactly like K/V minus the head_dim axis
-        ksf = jnp.moveaxis(_pad_to(k_scale, bk_, 1), 2, 1).reshape(
-            b * kvh, kp.shape[1])
-        vsf = jnp.moveaxis(_pad_to(v_scale, bk_, 1), 2, 1).reshape(
-            b * kvh, vp.shape[1])
-        scales = (ksf, vsf)
-    out = _fd.flash_decode(qf, kf, vf, lf, window=window, scale=scale,
-                           bk=bk_, scales=scales,
-                           interpret=(mode == "interpret"))
-    return out.reshape(b, h, hd)
+    if layer is None:
+        # a one-layer view of a per-layer cache (a free leading unit axis)
+        k, v = k[None], v[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+        layer = 0
+    scales = None if k_scale is None else (k_scale, v_scale)
+    rows = None
+    if new_row is not None:
+        rows = new_row[:2]
+        if k_scale is not None:
+            rows = tuple(r.astype(jnp.float32) * sc[..., None]
+                         for r, sc in zip(rows, new_row[2:]))
+    window_lo = None
+    if window is not None:
+        window_lo = lengths + (new_row is not None) - window
+    return _fd.flash_decode(q, k, v, lengths, layer, rows=rows,
+                            window_lo=window_lo, scale=scale, bk=bk,
+                            scales=scales, interpret=(mode == "interpret"))
 
 
 # ---------------------------------------------------------------------------

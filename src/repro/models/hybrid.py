@@ -56,11 +56,13 @@ def hybrid_layer_apply(p, cfg, x, extra, *, positions, rules=RULES):
     return x, jnp.zeros((), jnp.float32)
 
 
-def hybrid_layer_decode_rows(p, cfg, x_t, cache_l, pos, extra, *,
+def hybrid_layer_decode_rows(p, cfg, x_t, cache, li, pos, extra, *,
                              rules=RULES):
-    """Decode step against read-only {kv, mamba} per-layer views; emits
-    the attention branch's K/V rows and the SSD branch's new state for
-    the driver's single arena write (the rows/arena contract).
+    """Decode step of layer ``li`` against the read-only stacked {kv,
+    mamba} cache — the K/V arena read in place by flash-decode, the SSD
+    state sliced to the layer; emits the attention branch's K/V rows and
+    the SSD branch's new state for the driver's single arena write (the
+    rows/arena contract).
 
     Both branches ride the shared ``decode_and_sample`` driver: sampled
     decode stays deterministic under preemption because the attention KV
@@ -69,10 +71,10 @@ def hybrid_layer_decode_rows(p, cfg, x_t, cache_l, pos, extra, *,
     position) — see mamba2.ssm_layer_decode_rows for the recurrent-state
     half of that argument."""
     h = L.rmsnorm(p["ln1"], x_t, cfg.rms_eps)
-    a, rows = L.attention_decode_rows(p["attn"], cfg, h, cache_l["kv"], pos,
-                                      window=extra, rules=rules)
-    m, m_state = mamba2.mamba_decode_step(p["mamba"], cfg, h,
-                                          cache_l["mamba"], rules=rules)
+    a, rows = L.attention_decode_rows(p["attn"], cfg, h, cache["kv"], li,
+                                      pos, window=extra, rules=rules)
+    m, m_state = mamba2.mamba_decode_step(
+        p["mamba"], cfg, h, L.layer_view(cache["mamba"], li), rules=rules)
     mix = 0.5 * (L.rmsnorm(p["attn_norm"], a, cfg.rms_eps)
                  + L.rmsnorm(p["mamba_norm"], m, cfg.rms_eps))
     x_t = x_t + mix

@@ -359,50 +359,51 @@ def attention_chunk(p: dict, cfg, x: jax.Array, slot_kv: dict,
     return out, (k_rows, v_rows)
 
 
-def attention_decode_rows(p: dict, cfg, x_t: jax.Array, layer_kv: dict,
-                          pos: jax.Array, *, window: Optional[int] = None,
+def attention_decode_rows(p: dict, cfg, x_t: jax.Array, kv: dict,
+                          li: jax.Array, pos: jax.Array, *,
+                          window: Optional[int] = None,
                           rules=RULES) -> tuple[jax.Array, tuple]:
-    """One decode step against a read-only layer cache view, returning the
-    new K/V rows instead of a rewritten cache.
+    """One decode step of layer ``li`` against the read-only stacked arena,
+    returning the new K/V rows instead of a rewritten cache.
 
     The generic :func:`attention_decode` scatters into its cache argument
     and returns the whole updated layer cache; threading that through a
-    layer scan re-materialises the full arena every step.  Here the new
-    token's K/V rows are scattered into a *temporary* patched view only so
-    flash-decode can attend them; the caller (the dense arena driver)
+    layer scan re-materialises the full arena every step.  Here flash-decode
+    reads layer ``li`` of the stacked arena where it lies and takes the new
+    token's row as an operand of its own; the caller (the arena driver)
     collects the rows of every layer and writes them into the resident
-    arena with one in-place scatter.  x_t: (B, d); layer_kv: {"k","v"} of
-    (B, Smax, KVH, hd).  Returns (out, (k_row, v_row)) with rows shaped
-    (B, KVH, hd); scaled-format views quantize on write and return
-    (out, (k_row, v_row, k_scale, v_scale)) with scales shaped (B, KVH).
+    arena with one in-place scatter.  x_t: (B, d); kv: {"k","v"} of (L, B,
+    Smax, KVH, hd); li: int32 scalar.  Returns (out, (k_row, v_row)) with
+    rows shaped (B, KVH, hd); scaled-format arenas quantize on write and
+    return (out, (k_row, v_row, k_scale, v_scale)) with scales shaped
+    (B, KVH) — attention sees the row as later steps will read it back.
+
+    A slot attends arena rows [0, pos) and its new row at ``pos``.  A slot
+    whose ``pos`` lies outside the arena (``PARKED_POS``, mid-chunked-
+    prefill or idle) has no live arena rows: its output is discarded and
+    its row write dropped, so it attends the new row alone.
     """
     b, d = x_t.shape
     nh, hd = cfg.n_heads, cfg.hd
     q, k_t, v_t = _decode_qkv(p, cfg, x_t, pos, True)
-    scaled = "k_scale" in layer_kv
-    bidx = jnp.arange(b)
-    if scaled:
-        fmt = kvf.get(kv_cache_format(layer_kv))
+    if "k_scale" in kv:
+        fmt = kvf.get(kv_cache_format(kv))
         k_row, k_sc = kvf.quantize(fmt, k_t[:, 0])
         v_row, v_sc = kvf.quantize(fmt, v_t[:, 0])
+        rows = (k_row, v_row, k_sc, v_sc)
     else:
-        k_row = k_t[:, 0].astype(layer_kv["k"].dtype)
-        v_row = v_t[:, 0].astype(layer_kv["v"].dtype)
-    ck = layer_kv["k"].at[bidx, pos].set(k_row)
-    cv = layer_kv["v"].at[bidx, pos].set(v_row)
-    k_all = lanes.constrain(ck, rules, "batch", "kv_seq", None, None)
-    v_all = lanes.constrain(cv, rules, "batch", "kv_seq", None, None)
-    if scaled:
-        cks = layer_kv["k_scale"].at[bidx, pos].set(k_sc)
-        cvs = layer_kv["v_scale"].at[bidx, pos].set(v_sc)
-        o = ops.flash_decode(q[:, 0], k_all, v_all, lengths=pos + 1,
-                             window=window, k_scale=cks, v_scale=cvs)
-        out = _dot(o.reshape(b, nh * hd), p["wo"], cfg.adtype)
-        return out, (k_row, v_row, k_sc, v_sc)
-    o = ops.flash_decode(q[:, 0], k_all, v_all, lengths=pos + 1,
-                         window=window)
+        rows = (k_t[:, 0].astype(kv["k"].dtype),
+                v_t[:, 0].astype(kv["v"].dtype))
+    k_all = lanes.constrain(kv["k"], rules, None, "batch", "kv_seq", None,
+                            None)
+    v_all = lanes.constrain(kv["v"], rules, None, "batch", "kv_seq", None,
+                            None)
+    live = jnp.where(pos < k_all.shape[2], pos, 0)
+    o = ops.flash_decode(q[:, 0], k_all, v_all, lengths=live, layer=li,
+                         new_row=rows, window=window,
+                         k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"))
     out = _dot(o.reshape(b, nh * hd), p["wo"], cfg.adtype)
-    return out, (k_row, v_row)
+    return out, rows
 
 
 def init_kv_cache(cfg, batch: int, max_seq: int, dtype=None,
@@ -429,6 +430,11 @@ def init_kv_cache(cfg, batch: int, max_seq: int, dtype=None,
         cache["k_scale"] = ones
         cache["v_scale"] = ones
     return cache
+
+
+def layer_view(cache, li):
+    """Layer ``li``'s slice of a stacked cache pytree (leaf dim 0)."""
+    return jax.tree.map(lambda leaf: leaf[li], cache)
 
 
 def kv_cache_format(cache: dict) -> str:

@@ -223,13 +223,13 @@ def ssm_layer_apply(p, cfg, x, extra=None, *, positions=None, rules=RULES):
         jnp.zeros((), jnp.float32)
 
 
-def ssm_layer_decode_rows(p, cfg, x_t, cache_l, pos, extra=None, *,
+def ssm_layer_decode_rows(p, cfg, x_t, cache, li, pos, extra=None, *,
                           rules=RULES):
-    """Decode step against a read-only per-layer (ssm, conv) state view;
-    emits the layer's *new* state as the scan's ys instead of threading
-    the arena (the rows/arena contract — for a recurrent cache the "rows"
-    are the whole per-slot state, which the recurrence rewrites every
-    step anyway).
+    """Decode step of layer ``li`` against its slice of the read-only
+    stacked (ssm, conv) state; emits the layer's *new* state as the scan's
+    ys instead of threading the arena (the rows/arena contract — for a
+    recurrent cache the "rows" are the whole per-slot state, which the
+    recurrence rewrites every step anyway).
 
     Unlike KV caches the SSD state is not position-addressed, so a
     preempted slot cannot rewind it — recompute replays prefill from the
@@ -239,8 +239,8 @@ def ssm_layer_decode_rows(p, cfg, x_t, cache_l, pos, extra=None, *,
     position): the regenerated state sees the identical token/draw
     sequence, never a stored RNG cursor."""
     h = L.rmsnorm(p["ln"], x_t, cfg.rms_eps)
-    y, new_state = mamba_decode_step(p["mamba"], cfg, h, cache_l,
-                                     rules=rules)
+    y, new_state = mamba_decode_step(p["mamba"], cfg, h,
+                                     L.layer_view(cache, li), rules=rules)
     return x_t + y, new_state
 
 

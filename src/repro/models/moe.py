@@ -246,12 +246,13 @@ def moe_layer_chunk(p, cfg, x, kv_l, positions, start, nvalid, extra=None,
     return x + y, T.kv_emit_dict(rows)
 
 
-def moe_layer_decode_rows(p, cfg, x_t, kv_l, pos, extra=None, *,
+def moe_layer_decode_rows(p, cfg, x_t, kv, li, pos, extra=None, *,
                           rules=RULES):
-    """Decode step against a read-only layer KV view; emits the token's
-    K/V rows for the driver's single arena scatter (the rows/arena
-    contract — the old functional threading re-materialised the whole KV
-    arena every step through the layer scan's ys).
+    """Decode step of layer ``li`` against the read-only stacked KV arena
+    (read in place by flash-decode); emits the token's K/V rows for the
+    driver's single arena scatter (the rows/arena contract — the old
+    functional threading re-materialised the whole KV arena every step
+    through the layer scan's ys).
 
     Sampling caveat: the PRNG side of ``decode_and_sample`` is
     batch-composition independent for every family (keys fold only (seed,
@@ -261,7 +262,7 @@ def moe_layer_decode_rows(p, cfg, x_t, kv_l, pos, extra=None, *,
     donation, dispatch depth) while batch-membership invariance holds
     exactly when capacity never binds (see ``moe_layer_chunk``)."""
     h = L.rmsnorm(p["ln1"], x_t, cfg.rms_eps)
-    a, rows = L.attention_decode_rows(p["attn"], cfg, h, kv_l, pos,
+    a, rows = L.attention_decode_rows(p["attn"], cfg, h, kv, li, pos,
                                       rules=rules)
     x_t = x_t + a
     h = L.rmsnorm(p["ln2"], x_t, cfg.rms_eps)

@@ -87,13 +87,13 @@ def dense_layer_chunk(p, cfg, x, slot_kv, positions, start, *, window=None,
     return x, rows
 
 
-def dense_layer_decode_rows(p, cfg, x_t, layer_kv, pos, *, window=None,
+def dense_layer_decode_rows(p, cfg, x_t, kv, li, pos, *, window=None,
                             rules=RULES):
-    """One decode step through a dense layer against a read-only cache
-    view; returns the new K/V rows instead of a rewritten cache (see
-    :func:`repro.models.layers.attention_decode_rows`)."""
+    """One decode step through dense layer ``li`` against the read-only
+    stacked arena; returns the new K/V rows instead of a rewritten cache
+    (see :func:`repro.models.layers.attention_decode_rows`)."""
     h = L.rmsnorm(p["ln1"], x_t, cfg.rms_eps)
-    a, rows = L.attention_decode_rows(p["attn"], cfg, h, layer_kv, pos,
+    a, rows = L.attention_decode_rows(p["attn"], cfg, h, kv, li, pos,
                                       window=window, rules=rules)
     x_t = x_t + a
     h = L.rmsnorm(p["ln2"], x_t, cfg.rms_eps)
@@ -123,10 +123,10 @@ def _dense_layer_chunk_emit(p, cfg, x, kv_l, positions, start, *,
     return x, kv_emit_dict(rows)
 
 
-def _dense_layer_decode_emit(p, cfg, x_t, kv_l, pos, *, window=None,
+def _dense_layer_decode_emit(p, cfg, x_t, kv, li, pos, *, window=None,
                              rules=RULES):
     """Hook adapter: dense decode layer -> {"k","v"[,scales]} emission."""
-    x_t, rows = dense_layer_decode_rows(p, cfg, x_t, kv_l, pos,
+    x_t, rows = dense_layer_decode_rows(p, cfg, x_t, kv, li, pos,
                                         window=window, rules=rules)
     return x_t, kv_emit_dict(rows)
 
@@ -248,8 +248,9 @@ class LM:
 
     The serving hot path is family-pluggable through four hooks that share
     one contract — *the arena never rides the layer scan* (XLA's while-loop
-    copy insertion would clone it every layer); the scan reads per-layer
-    cache views and emits only what changed, and the driver writes the
+    copy insertion would clone it every layer); the scan reads the arena
+    (per-layer slot views when chunking, the stacked arena by layer index
+    when decoding) and emits only what changed, and the driver writes the
     resident arena exactly once per call:
 
       * ``layer_chunk(lp, cfg, x, view_l, positions, start, nvalid, extra)``
@@ -260,9 +261,12 @@ class LM:
       * ``chunk_scatter(cache, emits, slot, start)`` — write all layers'
         chunk emissions into slot ``slot`` of the arena (one scatter per
         leaf, in place under donation).
-      * ``layer_decode_rows(lp, cfg, x_t, view_l, pos, extra)`` — one
-        decode step against a read-only per-layer cache view; returns
-        ``(x_t, emit_l)`` (the token's K/V rows / the layer's new state).
+      * ``layer_decode_rows(lp, cfg, x_t, cache, li, pos, extra)`` — one
+        decode step of layer ``li`` against the read-only *stacked* cache
+        (K/V leaves are read in place by flash-decode; a hook slices
+        what else it needs, e.g. recurrent state, with
+        ``layers.layer_view``); returns ``(x_t, emit_l)`` (the token's
+        K/V rows / the layer's new state).
       * ``rows_scatter(cache, emits, pos)`` — write all layers' decode
         emissions into the arena at ``pos`` (parked slots —
         ``pos == layers.PARKED_POS`` — must be left untouched).
@@ -307,8 +311,8 @@ class LM:
             chunk_scatter = dense_chunk_scatter
         if layer_init is dense_layer_init and layer_decode_rows is None:
             layer_decode_rows = (
-                lambda lp, c, x_t, kv_l, pos, extra:
-                    _dense_layer_decode_emit(lp, c, x_t, kv_l, pos,
+                lambda lp, c, x_t, kv, li, pos, extra:
+                    _dense_layer_decode_emit(lp, c, x_t, kv, li, pos,
                                              window=extra, rules=self.rules))
             rows_scatter = dense_rows_scatter
         self._layer_chunk = layer_chunk
@@ -820,21 +824,25 @@ class LM:
         rows / new recurrent state), then one in-place write of everything
         into the resident arena via the family's ``rows_scatter``.
 
-        Under prefix sharing the scan reads through the composed view but
-        the scatter targets the raw arena — shared rows are never written.
+        The scan carries the layer index, not a slice of the arena: each
+        hook gets the whole stacked cache and ``li``, and flash-decode
+        reads layer ``li``'s K/V where they lie.  Under prefix sharing the
+        scan reads through the composed view but the scatter targets the
+        raw arena — shared rows are never written.
         """
         read = cache if share is None \
             else self._share_view(cache, share[0], share[1])
 
         def block(x_t, inp):
             if layer_xs is None:
-                lp, cache_l = inp
+                lp, li = inp
                 extra = None
             else:
-                lp, cache_l, extra = inp
-            return self._layer_decode_rows(lp, cfg, x_t, cache_l, pos, extra)
+                lp, li, extra = inp
+            return self._layer_decode_rows(lp, cfg, x_t, read, li, pos, extra)
 
-        xs = (params["layers"], read) if layer_xs is None \
-            else (params["layers"], read, layer_xs)
+        layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+        xs = (params["layers"], layer_ids) if layer_xs is None \
+            else (params["layers"], layer_ids, layer_xs)
         x_t, emits = lax.scan(block, x_t, xs)
         return x_t, self._rows_scatter(cache, emits, pos)

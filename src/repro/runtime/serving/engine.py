@@ -65,8 +65,10 @@ flash-decode tail predication hides rows ≥ the slot's live length, (b)
 prefill overwrites rows [0, prefill_len) — and a recurrent (SSD) state is
 explicitly re-zeroed by the first chunk / overwritten by the monolithic
 splice, and (c) a frozen slot's position pointer stops advancing
-(pos += active).  A slot undergoing *chunked* prefill additionally parks
-its position pointer at the ``PARKED_POS`` sentinel: the decode step's KV
+(pos += active).  A slot that leaves the decode batch, and a slot
+undergoing *chunked* prefill, additionally parks its position pointer at
+the ``PARKED_POS`` sentinel: flash-decode fetches none of its arena
+rows, the decode step's KV
 scatter for that row goes out of bounds and is dropped (XLA scatter
 semantics), and recurrent-state writes are keep-masked on
 ``pos < PARKED_POS`` (SSD state is not position-addressed, so the drop
@@ -90,6 +92,7 @@ import numpy as np
 from repro.core import kv_format as kv_format_mod
 from repro.core import masking, spans
 from repro.core.dispatch import DispatchQueue
+from repro.kernels.flash_decode import strip_counts
 from repro.models.layers import PARKED_POS
 from repro.runtime.serving import chunking, sampling
 from repro.runtime.serving.cache import (PagedKVCacheManager, PrefixMatch,
@@ -400,6 +403,11 @@ def _park_slot_jit(pos, slot, sentinel):
 
 
 @jax.jit
+def _retire_slot_jit(pos, active, slot, sentinel):
+    return pos.at[slot].set(sentinel), active.at[slot].set(0)
+
+
+@jax.jit
 def _set_share_jit(share, slot, src, ln):
     return {"src": share["src"].at[slot].set(src),
             "len": share["len"].at[slot].set(ln)}
@@ -551,6 +559,10 @@ class ServingEngine:
         self._tokens = jnp.zeros((max_slots,), jnp.int32)
         self._pos = jnp.zeros((max_slots,), jnp.int32)
         self._active = jnp.zeros((max_slots,), jnp.int32)
+        # the host's copies of pos/active, kept in step with every update
+        # the host sends (the decode step's pos += active included)
+        self._host_pos = np.zeros((max_slots,), np.int64)
+        self._host_active = np.zeros((max_slots,), np.int64)
         # per-slot sampling params (greedy until a sampled admission);
         # threaded through — and donated with — every decode step
         self.base_seed = int(config.base_seed)
@@ -590,7 +602,6 @@ class ServingEngine:
             self._decode_greedy = _compiled_decode_greedy(model, self.donate)
         self._use_sampling = False      # per-step executable choice
         self._insert = _insert_jit if self.donate else _insert_plain_jit
-        self._set_slot = _set_slot_jit
         # one prefill wrapper per model, compile-cached per prompt length
         self._prefill_fn = _compiled_prefill(model)
         # batch=1 zero cache reused by every monolithic admission (purely
@@ -684,7 +695,10 @@ class ServingEngine:
                       "arena_bytes": self.arena_bytes,
                       # engine steps, and PREFILLING slots summed once per
                       # step after admission (slot-steps held by prefill)
-                      "steps": 0, "slot_steps_prefilling": 0}
+                      "steps": 0, "slot_steps_prefilling": 0,
+                      # K/V strips flash-decode fetches per layer, summed
+                      # over decode steps
+                      "decode_kv_strips": 0}
         # decode-state buffers are donated into each step, so the queue
         # tracks a never-donated readback output (the sampled vector,
         # out[-2] — out[-1] is the ok-flag readback) for backpressure; its
@@ -753,7 +767,7 @@ class ServingEngine:
         of ``Scheduler.depart``)."""
         slot = self.scheduler.depart(st, status, reason)
         if slot is not None:
-            self._active = self._active.at[slot].set(0)
+            self._retire_slot(slot)
         key = {Status.TIMED_OUT: "timed_out",
                Status.MIGRATED: "migrated"}.get(status, "failed")
         self.stats[key] += 1
@@ -926,6 +940,7 @@ class ServingEngine:
                     # family's rows_scatter
                     self._pos = _park_slot_jit(self._pos, jnp.int32(st.slot),
                                                jnp.int32(PARKED_POS))
+                    self._host_pos[st.slot] = PARKED_POS
                     continue
                 if st.status != Status.RUNNING:
                     continue
@@ -998,15 +1013,30 @@ class ServingEngine:
         with spans.wait("first_token", self.stats):
             tok = int(token0)
         self._first_token(st)
-        self._tokens, self._pos, self._active = self._set_slot(
-            self._tokens, self._pos, self._active, jnp.int32(slot),
-            jnp.int32(tok), jnp.int32(pos0))
+        self._activate(slot, tok, pos0)
         self.stats["tokens_out"] += 1
         # first token may finish the request immediately, or its row
         # reservation may evict a younger running sequence — deactivate
         # every departed slot in the decode batch
         for dslot, _ in self.scheduler.on_token(slot, tok):
-            self._active = self._active.at[dslot].set(0)
+            self._retire_slot(dslot)
+
+    def _activate(self, slot: int, token: int, pos0: int) -> None:
+        """Put ``slot`` into the decode batch at ``token`` / ``pos0``."""
+        self._tokens, self._pos, self._active = _set_slot_jit(
+            self._tokens, self._pos, self._active, jnp.int32(slot),
+            jnp.int32(token), jnp.int32(pos0))
+        self._host_pos[slot] = pos0
+        self._host_active[slot] = 1
+
+    def _retire_slot(self, slot: int) -> None:
+        """Take ``slot`` out of the decode batch and park its position:
+        an idle slot attends no arena rows, so flash-decode fetches none
+        of it, and its garbage row write is dropped."""
+        self._pos, self._active = _retire_slot_jit(
+            self._pos, self._active, jnp.int32(slot), jnp.int32(PARKED_POS))
+        self._host_pos[slot] = PARKED_POS
+        self._host_active[slot] = 0
 
     def _prefill(self, prompt, one_cache, extras):
         # compile-cached per prompt length (bucket prompts upstream if
@@ -1396,11 +1426,9 @@ class ServingEngine:
                 # degraded): the device slot vectors lag the spec commits —
                 # resync tokens/pos from host state for every RUNNING slot
                 for st in running:
-                    self._tokens, self._pos, self._active = self._set_slot(
-                        self._tokens, self._pos, self._active,
-                        jnp.int32(st.slot), jnp.int32(st.generated[-1]),
-                        jnp.int32(st.prompt_len + self.prefix_extra
-                                  + len(st.generated) - 1))
+                    self._activate(st.slot, st.generated[-1],
+                                   st.prompt_len + self.prefix_extra
+                                   + len(st.generated) - 1)
                 self._spec_resync = False
             # executable choice: only a step with a sampled RUNNING slot
             # pays the sampling transform; pure-greedy steps run the argmax
@@ -1411,8 +1439,15 @@ class ServingEngine:
                      self._samp)
             if self.prefix_sharing:
                 state = state + (self._share,)
-            with spans.span("serving.decode", slots=len(running)):
+            # strips flash-decode fetches per layer, from the host's view
+            # of the positions this step decodes at (no device readback)
+            live = np.where(self._host_pos < self.max_seq, self._host_pos, 0)
+            kv_strips, grid_strips = strip_counts(live, self.max_seq)
+            with spans.span("serving.decode", slots=len(running),
+                            kv_strips=kv_strips, grid_strips=grid_strips):
                 out = self._queue.submit(state)
+            self._host_pos += self._host_active
+            self.stats["decode_kv_strips"] += kv_strips
             # rebind to the outputs: the submitted buffers were donated and
             # are dead from here on
             if self.prefix_sharing:
@@ -1463,7 +1498,7 @@ class ServingEngine:
                     deps = self.scheduler.on_token(slot,
                                                    int(host_tokens[slot]))
                     for dslot, _ in deps:
-                        self._active = self._active.at[dslot].set(0)
+                        self._retire_slot(dslot)
 
     def evacuate(self) -> list:
         """Remove every non-terminal request from service for migration and

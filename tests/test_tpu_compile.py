@@ -14,6 +14,8 @@ test file.  The persistent compilation cache is off around these compiles,
 because entries written for a described chip cannot be read back without
 one.
 """
+import re
+
 import pytest
 
 import jax
@@ -73,6 +75,22 @@ def _abstract(sharding, tree):
     return jax.tree.map(lambda x: _spec(sharding, x.shape, x.dtype), tree)
 
 
+_HLO_BYTES = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2, "s8": 1,
+              "u8": 1, "pred": 1}
+
+
+def _hlo_outputs(text):
+    """(name and opcode, shape, bytes) of every array-valued instruction
+    in an HLO module's text."""
+    for m in re.finditer(r"%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(",
+                         text):
+        name, dt, dims, op = m.groups()
+        n = _HLO_BYTES.get(dt, 4)
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        yield f"{name} {op}", dims, n
+
+
 def _compile(fn, *args, donate=()):
     compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
     text = compiled.as_text()
@@ -82,18 +100,23 @@ def _compile(fn, *args, donate=()):
 
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
 def test_flash_decode_compiles(one_chip, kv_dtype):
+    """The decode kernel over a stacked 2-layer arena read in place, rows
+    not a multiple of the strip, the new row as its own operand."""
     from repro.kernels import flash_decode as fd
     s = lambda shape, dt: _spec(one_chip, shape, dt)
-    bkv = SLOTS * KVH
-    args = [s((bkv, GROUP, HD), jnp.bfloat16),
-            s((bkv, ROWS, HD), kv_dtype), s((bkv, ROWS, HD), kv_dtype),
-            s((bkv,), jnp.int32)]
+    rows = 9 * 512 + 33
+    row_dt = jnp.float32 if kv_dtype == "int8" else kv_dtype
+    args = [s((SLOTS, KVH * GROUP, HD), jnp.bfloat16),
+            s((2, SLOTS, rows, KVH, HD), kv_dtype),
+            s((2, SLOTS, rows, KVH, HD), kv_dtype),
+            s((SLOTS,), jnp.int32), s((), jnp.int32),
+            s((SLOTS, KVH, HD), row_dt), s((SLOTS, KVH, HD), row_dt)]
     if kv_dtype == "int8":
-        args += [s((bkv, ROWS), jnp.float32), s((bkv, ROWS), jnp.float32)]
-        _compile(lambda q, k, v, n, ks, vs: fd.flash_decode(
-            q, k, v, n, scales=(ks, vs)), *args)
-    else:
-        _compile(fd.flash_decode, *args)
+        args += [s((2, SLOTS, rows, KVH), jnp.float32)] * 2
+    compiled = _compile(lambda q, k, v, n, li, kr, vr, *sc: fd.flash_decode(
+        q, k, v, n, li, rows=(kr, vr), scales=sc or None), *args)
+    # the arena reaches the kernel as it is stored: no copy of it
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 @pytest.mark.parametrize("chunk", [32, 512])
@@ -152,6 +175,16 @@ def test_llama_decode_step_fits_v5e(one_chip, llama, pallas_mode):
                 - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert mem.alias_size_in_bytes > 0          # the arena is donated
     assert resident < V5E_HBM_BYTES, mem
+    # flash_decode reads the arena in place: no per-layer slice, pad,
+    # transpose or copy of it, so no temporary near one layer's K
+    k = cache["k"]
+    layer_k_bytes = k.size // k.shape[0] * k.dtype.itemsize
+    assert mem.temp_size_in_bytes < layer_k_bytes, mem
+    relayouts = [(name, shape) for name, shape, nbytes in
+                 _hlo_outputs(compiled.as_text())
+                 if nbytes >= layer_k_bytes
+                 and re.search(r"copy|pad|transpose|dynamic.slice", name)]
+    assert not relayouts, relayouts
 
 
 def test_llama_chunk_step_compiles(one_chip, llama, pallas_mode):
