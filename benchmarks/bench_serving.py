@@ -863,7 +863,7 @@ def _memory_sweep(report, model, params, *, smoke: bool):
 
 # tiny family configs for the claim lowering (dense is covered by
 # _memory_sweep) — the same single-source regime the engine tests pin
-# (configs.base.tiny_family_configs: MoE capacity never binds ⟹
+# (configs.base.tiny_family_configs: MoE serving is dropless ⟹
 # chunked/batched serving bit-identical to sequential), at the bench's
 # slightly larger width.
 _FAMILY_CFGS = tiny_family_configs(d_model=64, vocab=128, max_seq=128,

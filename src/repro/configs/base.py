@@ -21,9 +21,19 @@ class MoEConfig:
     d_ff_expert: int = 0
     n_shared_experts: int = 0
     d_ff_shared: int = 0          # total shared-expert hidden width
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25         # training dispatch only
     router_aux_weight: float = 0.01
     router_z_weight: float = 1e-3
+    norm_topk_prob: bool = True           # top-k weights divided by their sum
+    # the experts this chip holds: ``experts_held`` of them (0: all
+    # ``n_experts``) starting at ``first_expert``; routing still spans all
+    # ``n_experts``, and rows routed elsewhere add nothing here
+    experts_held: int = 0
+    first_expert: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,7 +110,7 @@ class ArchConfig:
             mlp = 2 * d * self.d_ff
         if self.moe:
             me = self.moe
-            emlp = (3 * d * me.d_ff_expert) * me.n_experts
+            emlp = (3 * d * me.d_ff_expert) * me.held   # held here
             if me.n_shared_experts:
                 emlp += 3 * d * me.d_ff_shared + d  # + shared gate
             emlp += d * me.n_experts                # router
@@ -187,12 +197,10 @@ def tiny_family_configs(*, d_model: int = 32, vocab: int = 97,
     regime (used by tests/conftest.py and benchmarks/bench_serving.py so
     the regime cannot drift between the suites and the bench claims).
 
-    The load-bearing knob: MoE ``capacity_factor = n_experts / top_k``
-    ⟹ expert capacity never binds for any routing ⟹ MoE logits are
-    per-token, so chunked/batched serving is bit-identical to sequential
-    generation (the regime the engine-equivalence tests compare in; under
-    binding capacity the dispatch buffer couples tokens — the documented
-    MoE caveat)."""
+    MoE serves through the dropless expert layer (``models/moe.py``):
+    every routed row is computed, so MoE logits are per-token and chunked
+    or batched serving matches sequential generation without any capacity
+    setting."""
     hd = d_model // 4
     f32 = dict(param_dtype="float32", act_dtype="float32")
     return {
@@ -208,8 +216,7 @@ def tiny_family_configs(*, d_model: int = 32, vocab: int = 97,
                           n_kv_heads=2, d_ff=2 * d_model, vocab=vocab,
                           head_dim=hd,
                           moe=MoEConfig(n_experts=4, top_k=2,
-                                        d_ff_expert=32,
-                                        capacity_factor=2.0),
+                                        d_ff_expert=32),
                           max_seq=max_seq, **f32),
         "ssm": ArchConfig(name=f"{name_prefix}-ssm", family="ssm",
                           n_layers=2, d_model=d_model, n_heads=8,

@@ -1,4 +1,4 @@
-"""qwen3-14b — dense, qk_norm, GQA [hf:Qwen/Qwen3-8B family]."""
+"""qwen3-14b — dense, qk_norm, GQA [hf:Qwen/Qwen3-14B]."""
 from repro.configs.base import ArchConfig
 
 CONFIG = ArchConfig(
@@ -13,5 +13,6 @@ CONFIG = ArchConfig(
     act="silu_gated",
     qk_norm=True,
     rope_theta=1_000_000.0,
+    rms_eps=1e-6,
     max_seq=32_768,
 )

@@ -1,6 +1,9 @@
 """qwen3-moe-30b-a3b — 128 experts top-8, qk_norm [hf:Qwen/Qwen3-30B-A3B].
 
-head_dim=128 explicit (HF config; q-dim 4096 != d_model 2048).
+head_dim=128 explicit (HF config; q-dim 4096 != d_model 2048).  Every
+layer is MoE (``mlp_only_layers`` empty, ``decoder_sparse_step`` 1), no
+shared expert, top-8 weights renormalised (``norm_topk_prob``); the
+unused dense ``intermediate_size`` 6144 is not modelled.
 """
 from repro.configs.base import ArchConfig, MoEConfig
 
@@ -17,6 +20,8 @@ CONFIG = ArchConfig(
     act="silu_gated",
     qk_norm=True,
     rope_theta=1_000_000.0,
-    moe=MoEConfig(n_experts=128, top_k=8, d_ff_expert=768),
-    max_seq=32_768,
+    rms_eps=1e-6,
+    moe=MoEConfig(n_experts=128, top_k=8, d_ff_expert=768,
+                  norm_topk_prob=True, router_aux_weight=0.001),
+    max_seq=40_960,
 )

@@ -26,6 +26,7 @@ from jax import lax
 from repro.core import masking, stripmine
 from repro.kernels import conv2d as _conv2d
 from repro.kernels import dotp as _dotp
+from repro.kernels import expert_gmm as _egmm
 from repro.kernels import flash_attention as _fa
 from repro.kernels import flash_decode as _fd
 from repro.kernels import flash_prefill_chunk as _fpc
@@ -79,6 +80,32 @@ def matmul(a: jax.Array, b: jax.Array, *, bm: int = _matmul.DEFAULT_BM,
     out = _matmul.matmul(ap, bp, bm=bm_, bk=bk_, bn=bn_,
                          interpret=(mode == "interpret"))
     return out[:m, :n]
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul over the experts held here
+# ---------------------------------------------------------------------------
+
+def expert_gmm(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array, *,
+               layer: Optional[jax.Array] = None,
+               mode: Optional[Mode] = None) -> jax.Array:
+    """``lhs`` (M, K) rows sorted by expert (expert g owns ``sizes[g]``
+    consecutive rows), ``rhs`` (G, K, N), or (L, G, K, N) read at ``layer``
+    -> (M, N) in ``lhs``'s dtype, f32 accumulation.  Rows past
+    ``sizes.sum()`` hold no defined value on the kernel path.  The row
+    buffer is padded to the kernel's row tile."""
+    mode = mode or _resolved()
+    if layer is None:
+        rhs, layer = rhs[None], 0
+    if mode == "ref":
+        return ref.expert_gmm(lhs, rhs[layer], sizes).astype(lhs.dtype)
+    m, k = lhs.shape
+    tm = _egmm.row_tile(m)
+    tn = _egmm.col_tile(k, rhs.shape[-1], rhs.dtype.itemsize)
+    out = _egmm.expert_gmm(_pad_to(lhs, tm, 0), rhs, sizes,
+                           jnp.asarray(layer, jnp.int32), tm=tm, tn=tn,
+                           interpret=(mode == "interpret"))
+    return out[:m]
 
 
 # ---------------------------------------------------------------------------

@@ -81,3 +81,22 @@ def ssd(x: jax.Array, log_a: jax.Array, B: jax.Array, C: jax.Array,
 
     final, y = lax.scan(step, st0, (x32, la, B32, C32))
     return y.astype(x.dtype), final
+
+
+def expert_gmm(lhs: jax.Array, rhs: jax.Array,
+               sizes: jax.Array) -> jax.Array:
+    """Grouped matmul oracle: ``lhs`` (M, K) rows sorted by group, group
+    ``g`` owning ``sizes[g]`` consecutive rows; row r times ``rhs[g]`` of
+    its group (rhs (G, K, N)), f32; rows past ``sizes.sum()`` are zero."""
+    ends = jnp.cumsum(sizes)
+    row = jnp.arange(lhs.shape[0])
+    group = jnp.sum(row[:, None] >= ends[None, :], axis=1)    # G: no group
+    a = lhs.astype(jnp.float32)
+
+    def one(g, out):
+        y = jnp.dot(a, rhs[g].astype(jnp.float32),
+                    preferred_element_type=jnp.float32)
+        return jnp.where((group == g)[:, None], y, out)
+
+    out = jnp.zeros((lhs.shape[0], rhs.shape[2]), jnp.float32)
+    return lax.fori_loop(0, rhs.shape[0], one, out)

@@ -307,11 +307,12 @@ def ssm_chunk_scatter(cache, emits, slot, start):
     under donation, O(slot state) bytes per chunk independent of the slot
     count and the chunk's position.  An out-of-range (parked/sentinel)
     ``slot`` scatters out of bounds and is dropped."""
-    nh = emits["ssm"].shape[1]
-    hidx = slot * nh + jnp.arange(nh)
-    return {"ssm": cache["ssm"].at[:, hidx].set(
+    nl, nh = emits["ssm"].shape[:2]
+    li = jnp.arange(nl)[:, None]       # every row indexed by its layer too,
+    hidx = slot * nh + jnp.arange(nh)  # as dense_chunk_scatter does
+    return {"ssm": cache["ssm"].at[li, hidx[None, :]].set(
                 emits["ssm"].astype(cache["ssm"].dtype)),
-            "conv": cache["conv"].at[:, slot].set(
+            "conv": cache["conv"].at[li[:, 0], slot].set(
                 emits["conv"][:, 0].astype(cache["conv"].dtype))}
 
 

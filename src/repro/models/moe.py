@@ -1,6 +1,26 @@
-"""Mixture-of-Experts layer: top-k routing with capacity predication (C3).
+"""Mixture-of-Experts layer: top-k routing, two dispatch paths.
 
-Routing is implemented sort-free via cumulative-count positioning:
+Serving (:func:`moe_mlp_serve`, every serving hook) is **dropless** and
+computes only the experts this chip holds (``MoEConfig.experts_held``
+from ``first_expert``; all of them by default):
+
+  1. router logits over all ``n_experts`` in f32, softmax, top-k, weights
+     renormalised over the k when ``norm_topk_prob``,
+  2. the (token, choice) rows that land on a held expert are sorted by
+     expert (a one-hot cumsum gives each row its place; no sort, no
+     capacity) — rows routed elsewhere, pad tokens and parked slots land
+     nowhere,
+  3. ``expert_gmm`` (kernels/expert_gmm.py) multiplies each expert's rows
+     by its gate and up weights, then silu(gate) * up by its down weights,
+     fetching no weight of an expert that received no row,
+  4. each row returns to its token scaled by its routing weight.
+
+Nothing is dropped, so a token's output never depends on its batch-mates.
+The layer also counts, per call, the rows routed here and the held
+experts that received at least one (the engine's ``moe_*`` stats).
+
+Training (:func:`moe_mlp_apply`) keeps capacity dispatch, implemented
+sort-free via cumulative-count positioning:
 
   1. router logits -> top-k experts + gates per token,
   2. position-in-expert via a masked cumsum over the (tokens·k, E) one-hot
@@ -15,7 +35,7 @@ The dispatch/combine gathers are the MoE "monolithic crossbar" (paper
 Eq. 2): under GSPMD they lower to all-to-all/all-gather traffic measured by
 the collective roofline term; the hierarchical alternative is a §Perf
 iteration.  A Switch-style load-balance aux loss + router z-loss are
-returned for the trainer.
+returned for the trainer.  Training holds every expert.
 """
 from __future__ import annotations
 
@@ -24,9 +44,13 @@ import jax.numpy as jnp
 from jax.sharding import AxisType
 
 from repro.core import lanes
+from repro.kernels import ops
 from repro.models import layers as L
 
 RULES = L.RULES
+
+# the serving layer's step counters, in the order of its ``counts``
+COUNTERS = ("moe_rows_here", "moe_experts_touched")
 
 
 def moe_mlp_init(key, cfg) -> dict:
@@ -46,7 +70,7 @@ def moe_mlp_init(key, cfg) -> dict:
     p = {
         "router": (jax.random.normal(kr, (d, me.n_experts)) * s_in)
         .astype(jnp.float32),
-        "experts": jax.vmap(expert_block)(jax.random.split(ke, me.n_experts)),
+        "experts": jax.vmap(expert_block)(jax.random.split(ke, me.held)),
     }
     if me.n_shared_experts:
         p["shared"] = L.mlp_init(ks, d, me.d_ff_shared, "silu_gated",
@@ -117,6 +141,9 @@ def moe_mlp_apply(p, cfg, x, *, rules=RULES):
 def _moe_mlp_global(p, cfg, x, *, rules=RULES):
     """Routing + dispatch + expert MLPs + combine over x's token axis."""
     me = cfg.moe
+    if me.held != me.n_experts:
+        raise ValueError("capacity dispatch needs every expert held; "
+                         "serve a partial share with moe_mlp_serve")
     b, s, d = x.shape
     t = b * s
     e, k = me.n_experts, me.top_k
@@ -190,6 +217,66 @@ def _moe_mlp_global(p, cfg, x, *, rules=RULES):
     return lanes.constrain(y, rules, "batch", None, "embed"), aux
 
 
+def route(router, me, h):
+    """h (T, d) -> (experts (T, k) int32, weights (T, k) f32): softmax of
+    the f32 router logits over all ``n_experts``, the top k, renormalised
+    over the k when ``norm_topk_prob``."""
+    logits = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    weights, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                     me.top_k)
+    if me.norm_topk_prob:
+        weights = weights / weights.sum(-1, keepdims=True)
+    return experts, weights
+
+
+def moe_mlp_serve(p, cfg, x, valid):
+    """The dropless expert layer over the experts held here.
+
+    x: (..., d); valid: x's leading shape, bool — False rows (chunk
+    padding, parked slots) route nowhere.  ``p["experts"]`` is this
+    layer's, or, under ``p["layer"]``, every layer's stacked (read in
+    place by ``expert_gmm``).  Returns (y like x, counts (2,)
+    int32: rows routed to held experts, held experts with a row)."""
+    me = cfg.moe
+    lead, d = x.shape[:-1], x.shape[-1]
+    xf = x.reshape(-1, d)
+    t, k, held = xf.shape[0], me.top_k, me.held
+    experts, weights = route(p["router"], me, xf)
+    local = experts - me.first_expert
+    here = (local >= 0) & (local < held) & valid.reshape(t, 1)   # (T, k)
+    # each row's place in the expert-sorted buffer: held experts' rows
+    # first, in expert order, then every row routed elsewhere
+    group = jnp.where(here, local, held).reshape(-1)              # (T*k,)
+    onehot = jax.nn.one_hot(group, held + 1, dtype=jnp.int32)
+    sizes = onehot.sum(0)
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, 0) - onehot,
+                               group[:, None], axis=1)[:, 0]
+    dest = jnp.cumsum(sizes)[group] - sizes[group] + rank
+    token = jnp.zeros((t * k,), jnp.int32).at[dest].set(
+        jnp.arange(t * k, dtype=jnp.int32) // k)
+    rows = xf[token]                                              # sorted
+    sizes = sizes[:held]
+    we, li = p["experts"], p.get("layer")
+    gate = ops.expert_gmm(rows, we["w_gate"], sizes, layer=li)
+    up = ops.expert_gmm(rows, we["w_up"], sizes, layer=li)
+    h = (jax.nn.silu(gate.astype(jnp.float32))
+         * up.astype(jnp.float32)).astype(cfg.adtype)
+    out = ops.expert_gmm(h, we["w_down"], sizes, layer=li)
+    # rows past the held experts' hold no defined value: select, never
+    # multiply, them away
+    back = out[dest].reshape(t, k, d).astype(jnp.float32)
+    y = jnp.where(here[..., None], back * weights[..., None], 0.0).sum(1)
+    if me.n_shared_experts:
+        sh = L.mlp(p["shared"], cfg, xf, act="silu_gated")
+        sgate = jax.nn.sigmoid(
+            jnp.dot(xf.astype(jnp.float32), p["shared_gate"]
+                    .astype(jnp.float32)))
+        y = y + sh.astype(jnp.float32) * sgate
+    counts = jnp.stack([sizes.sum(), (sizes > 0).sum()]).astype(jnp.int32)
+    return y.astype(cfg.adtype).reshape(*lead, d), counts
+
+
 def moe_layer_init(key, cfg) -> dict:
     ka, km = jax.random.split(key)
     return {
@@ -211,61 +298,49 @@ def moe_layer_apply(p, cfg, x, extra=None, *, positions, rules=RULES):
 
 def moe_prefill_layer(p, cfg, x, cache_l, positions, extra=None, *,
                       rules=RULES):
-    """Prefill: attention + KV fill (shared helper) + MoE MLP."""
+    """Prefill: attention + KV fill (shared helper) + the dropless expert
+    layer over every prompt token."""
     from repro.models import transformer as T
     h = L.rmsnorm(p["ln1"], x, cfg.rms_eps)
     a, cache_l = T.attention_prefill(p["attn"], cfg, h, cache_l, positions,
                                      rules=rules)
     x = x + a
     h = L.rmsnorm(p["ln2"], x, cfg.rms_eps)
-    y, _ = moe_mlp_apply(p["moe"], cfg, h, rules=rules)
+    y, _ = moe_mlp_serve(p["moe"], cfg, h, jnp.ones(h.shape[:-1], bool))
     return x + y, cache_l
 
 
 def moe_layer_chunk(p, cfg, x, kv_l, positions, start, nvalid, extra=None,
                     *, rules=RULES):
     """One prompt chunk through an MoE layer: chunk-append attention over
-    the slot's KV prefix + the expert MLP on the chunk's tokens; emits the
-    chunk's K/V rows for the driver's single arena scatter (the cache is
-    pure KV — routing has no recurrent state to thread).
-
-    Capacity caveat: the expert capacity of a chunk is proportional to
-    the *chunk's* tokens (as monolithic prefill's is to the prompt's), so
-    chunked and monolithic prefill agree bit-for-bit exactly when
-    capacity never binds (``capacity_factor >= n_experts / top_k``
-    guarantees zero drops for any routing); under binding capacity the
-    outputs are shape-correct but may drop different tokens — the same
-    caveat as batched MoE decode vs sequential."""
+    the slot's KV prefix + the dropless expert layer on the chunk's first
+    ``nvalid`` tokens (padding routes nowhere); emits the chunk's K/V rows
+    for the driver's single arena scatter and the layer's step counters
+    (``T.COUNTS``)."""
+    from repro.models import transformer as T
     h = L.rmsnorm(p["ln1"], x, cfg.rms_eps)
     a, rows = L.attention_chunk(p["attn"], cfg, h, kv_l, positions, start,
                                 rules=rules)
     x = x + a
     h = L.rmsnorm(p["ln2"], x, cfg.rms_eps)
-    y, _ = moe_mlp_apply(p["moe"], cfg, h, rules=rules)
-    from repro.models import transformer as T
-    return x + y, T.kv_emit_dict(rows)
+    valid = jnp.arange(x.shape[1])[None, :] < nvalid
+    y, counts = moe_mlp_serve(p["moe"], cfg, h,
+                              jnp.broadcast_to(valid, h.shape[:-1]))
+    return x + y, dict(T.kv_emit_dict(rows), **{T.COUNTS: counts})
 
 
 def moe_layer_decode_rows(p, cfg, x_t, kv, li, pos, extra=None, *,
                           rules=RULES):
     """Decode step of layer ``li`` against the read-only stacked KV arena
     (read in place by flash-decode); emits the token's K/V rows for the
-    driver's single arena scatter (the rows/arena contract — the old
-    functional threading re-materialised the whole KV arena every step
-    through the layer scan's ys).
-
-    Sampling caveat: the PRNG side of ``decode_and_sample`` is
-    batch-composition independent for every family (keys fold only (seed,
-    position)), but MoE *logits* are not — capacity dropping couples the
-    slots sharing a dispatch buffer — so a sampled MoE stream is
-    deterministic for a fixed slot-batch trajectory (preemption replay,
-    donation, dispatch depth) while batch-membership invariance holds
-    exactly when capacity never binds (see ``moe_layer_chunk``)."""
+    driver's single arena scatter and the layer's step counters.  A parked
+    slot (``pos == PARKED_POS``) routes nowhere, so it fetches no expert's
+    weights."""
+    from repro.models import transformer as T
     h = L.rmsnorm(p["ln1"], x_t, cfg.rms_eps)
     a, rows = L.attention_decode_rows(p["attn"], cfg, h, kv, li, pos,
                                       rules=rules)
     x_t = x_t + a
     h = L.rmsnorm(p["ln2"], x_t, cfg.rms_eps)
-    y, _ = moe_mlp_apply(p["moe"], cfg, h[:, None, :], rules=rules)
-    from repro.models import transformer as T
-    return x_t + y[:, 0], T.kv_emit_dict(rows)
+    y, counts = moe_mlp_serve(p["moe"], cfg, h, pos < L.PARKED_POS)
+    return x_t + y, dict(T.kv_emit_dict(rows), **{T.COUNTS: counts})

@@ -72,6 +72,7 @@ def build_model(cfg: ArchConfig, rules=None):
         lm._prefill_layer = lambda lp, c, x, cache_l, positions, extra: \
             M.moe_prefill_layer(lp, c, x, cache_l, positions, extra,
                                 rules=lm.rules)
+        lm.counters = M.COUNTERS
         return lm
     if cfg.family == "ssm":
         lm = T.LM(

@@ -101,6 +101,41 @@ def dense_layer_decode_rows(p, cfg, x_t, kv, li, pos, *, window=None,
     return x_t, rows
 
 
+# emission key of a layer hook's step counters: an int32 vector the driver
+# sums over layers and returns beside the step's outputs (``with_counts``);
+# the family's ``LM.counters`` names its entries
+COUNTS = "counts"
+
+
+def _split_counts(emits):
+    """(emits without the counters, counters summed over layers or None)."""
+    if not (isinstance(emits, dict) and COUNTS in emits):
+        return emits, None
+    emits = dict(emits)
+    return emits, emits.pop(COUNTS).sum(0)
+
+
+def _split_resident(layers):
+    """(stacked layer params the scan slices, the expert weights or None).
+
+    The expert layer reads its weights where they lie, at the layer index
+    the scan carries: ``expert_gmm`` is a Pallas call, which cannot fuse
+    the scan's per-layer slice, so a sliced operand would first be copied
+    whole — every held expert's weights, each layer of each step."""
+    moe = layers.get("moe") if isinstance(layers, dict) else None
+    if not (isinstance(moe, dict) and "experts" in moe):
+        return layers, None
+    rest = {k: v for k, v in moe.items() if k != "experts"}
+    return dict(layers, moe=rest), moe["experts"]
+
+
+def _with_resident(lp, experts, li):
+    """Layer ``li``'s params with the whole expert stack and its index."""
+    if experts is None:
+        return lp
+    return dict(lp, moe=dict(lp["moe"], experts=experts, layer=li))
+
+
 def kv_emit_dict(rows) -> dict:
     """K/V row emission dict from a layer hook's ``rows`` tuple.
 
@@ -138,15 +173,20 @@ def dense_chunk_scatter(cache, emits, slot, start):
     {"k_scale","v_scale"} of (L, 1, C, KVH) for scaled formats (the same
     three leading index dims, so one scatter expression covers both).  The
     write is a single scatter per leaf at rows [start, start + C) of the
-    slot, which lowers in place under buffer donation.  Scatter (not
+    slot, each row indexed by (layer, slot, row) as the decode scatter
+    indexes its rows, which lowers in place under buffer donation: with
+    the layer axis left as a window, the v5e compiler re-lays out a
+    4-KV-head arena around the scatter (two copies of each leaf per
+    chunk).  Scatter (not
     ``dynamic_update_slice``) deliberately: an out-of-range ``slot`` (a
     parked/sentinel index ≥ the slot count) is *dropped* by XLA scatter
     semantics, where dynamic_update_slice would clamp it onto the last
     live slot's rows and corrupt them.
     """
-    c = emits["k"].shape[2]
-    idx = start + jnp.arange(c)
-    return {key: cache[key].at[:, slot, idx].set(
+    nl, _, c = emits["k"].shape[:3]
+    li = jnp.broadcast_to(jnp.arange(nl)[:, None], (nl, c))
+    pi = jnp.broadcast_to(start + jnp.arange(c)[None, :], (nl, c))
+    return {key: cache[key].at[li, slot, pi].set(
                 emits[key][:, 0].astype(cache[key].dtype))
             for key in emits}
 
@@ -317,6 +357,9 @@ class LM:
             rows_scatter = dense_rows_scatter
         self._layer_chunk = layer_chunk
         self._chunk_scatter = chunk_scatter
+        # names of the step counters the layer hooks emit under ``COUNTS``
+        # (empty: the family counts nothing and its steps return none)
+        self.counters: tuple = ()
         self._layer_decode_rows = layer_decode_rows
         self._rows_scatter = rows_scatter
         # per-family serving capabilities: chunked (stripmined) prefill and
@@ -614,7 +657,7 @@ class LM:
         return jax.tree.map(view, cache, factors)
 
     def prefill_chunk(self, params, tokens, cache, slot, start, last_idx,
-                      share_src=None, share_len=None):
+                      share_src=None, share_len=None, with_counts=False):
         """Stripmined prefill: ingest one prompt chunk straight into slot
         ``slot`` of the resident cache arena.
 
@@ -655,18 +698,23 @@ class LM:
         request's chunks all start at ``start >= share_len``, so the
         scatter below still only ever writes the slot's own private rows.
         ``None`` (the default) keeps the original executable untouched.
+
+        ``with_counts``: also return the step counters the layers emit
+        (``self.counters``; None when the family counts nothing).
         """
         if not self.supports_chunked_prefill:
             raise NotImplementedError(
                 f"chunked prefill not supported for family "
                 f"{self.cfg.family!r}")
-        h, new_cache = self._chunk_hidden(params, tokens, cache, slot, start,
-                                          last_idx + 1, share_src=share_src,
-                                          share_len=share_len)
+        h, new_cache, counts = self._chunk_hidden(
+            params, tokens, cache, slot, start, last_idx + 1,
+            share_src=share_src, share_len=share_len)
         last = lax.dynamic_slice_in_dim(h, last_idx, 1, axis=1)[:, 0]
         logits = jnp.dot(last, self.head(params),
                          preferred_element_type=jnp.float32)
         logits = lanes.constrain(logits, self.rules, "batch", "vocab_tp")
+        if with_counts:
+            return logits, new_cache, counts
         return logits, new_cache
 
     def _chunk_hidden(self, params, tokens, cache, slot, start, nvalid,
@@ -675,7 +723,8 @@ class LM:
         :meth:`verify_chunk`: embed the chunk, run every layer's chunk hook
         against the slot's read-only arena view, write the emissions back
         with one ``chunk_scatter``, and return the final-norm hidden states
-        for *all* C rows (the caller picks which rows become logits)."""
+        for *all* C rows (the caller picks which rows become logits), and
+        the layers' step counters (None when the family counts nothing)."""
         cfg = self.cfg
         b, c = tokens.shape
         x = L.embed_lookup(params["embed"], tokens, self.rules)
@@ -687,22 +736,30 @@ class LM:
             slot_view = self._share_slot_view(cache, slot, share_src,
                                               share_len)
 
+        layers, experts = _split_resident(params["layers"])
+
         def block(carry, inp):
             x = carry
             if layer_xs is None:
-                lp, view_l = inp
+                lp, li, view_l = inp
                 extra = None
             else:
-                lp, view_l, extra = inp
-            x, emit = self._layer_chunk(lp, cfg, x, view_l, positions,
-                                        start, nvalid, extra)
+                lp, li, view_l, extra = inp
+            x, emit = self._layer_chunk(_with_resident(lp, experts, li), cfg,
+                                        x, view_l, positions, start, nvalid,
+                                        extra)
             return x, emit
 
-        xs = (params["layers"], slot_view) if layer_xs is None \
-            else (params["layers"], slot_view, layer_xs)
+        # the layer index only where the expert stack is read by it
+        layer_ids = (None if experts is None
+                     else jnp.arange(cfg.n_layers, dtype=jnp.int32))
+        xs = (layers, layer_ids, slot_view) if layer_xs is None \
+            else (layers, layer_ids, slot_view, layer_xs)
         x, emits = lax.scan(block, x, xs)
+        emits, counts = _split_counts(emits)
         new_cache = self._chunk_scatter(cache, emits, slot, start)
-        return L.rmsnorm(params["final_norm"], x, cfg.rms_eps), new_cache
+        return (L.rmsnorm(params["final_norm"], x, cfg.rms_eps), new_cache,
+                counts)
 
     def verify_chunk(self, params, tokens, cache, slot, start):
         """Speculative-verify driver: run C already-proposed tokens through
@@ -734,8 +791,8 @@ class LM:
                 f"speculative verify not supported for family "
                 f"{self.cfg.family!r} (needs the chunked-prefill hooks)")
         b, c = tokens.shape
-        h, new_cache = self._chunk_hidden(params, tokens, cache, slot, start,
-                                          jnp.int32(c))
+        h, new_cache, _ = self._chunk_hidden(params, tokens, cache, slot,
+                                             start, jnp.int32(c))
         logits = jnp.dot(h, self.head(params),
                          preferred_element_type=jnp.float32)
         logits = lanes.constrain(logits, self.rules, "batch", None,
@@ -757,7 +814,8 @@ class LM:
     def _extra_window(extra):
         return None if extra is None else extra
 
-    def decode_step(self, params, token_t, cache, pos, share=None):
+    def decode_step(self, params, token_t, cache, pos, share=None,
+                    with_counts=False):
         """token_t: (B,) int32; pos: (B,) position to write. Returns
         (logits (B,V), new_cache).
 
@@ -773,21 +831,26 @@ class LM:
         view (:meth:`_share_view`) while ``rows_scatter`` still writes the
         raw arena, so shared prefix rows are read in place from the donor
         slot and never written.
+
+        ``with_counts``: also return the step counters the layers emit
+        (``self.counters``; None when the family counts nothing).
         """
         cfg = self.cfg
         x_t = L.embed_lookup(params["embed"], token_t[:, None],
                              self.rules)[:, 0]
         layer_xs = self._layer_xs_fn(cfg) if self._layer_xs_fn else None
-        x_t, new_cache = self._decode_rows(params, cfg, x_t, cache, pos,
-                                           layer_xs, share=share)
+        x_t, new_cache, counts = self._decode_rows(params, cfg, x_t, cache,
+                                                   pos, layer_xs, share=share)
         h = L.rmsnorm(params["final_norm"], x_t, cfg.rms_eps)
         logits = jnp.dot(h, self.head(params),
                          preferred_element_type=jnp.float32)
         logits = lanes.constrain(logits, self.rules, "batch", "vocab_tp")
+        if with_counts:
+            return logits, new_cache, counts
         return logits, new_cache
 
     def decode_and_sample(self, params, token_t, cache, pos, samp,
-                          share=None, with_flags=False):
+                          share=None, with_flags=False, with_counts=False):
         """One decode step + on-device sampling: the serving engine's
         compiled step body, shared by every LM family (all on the
         rows/arena decode path via their ``layer_decode_rows`` /
@@ -808,15 +871,19 @@ class LM:
         ``(tok, ok, new_cache)``.  The serving engine's quarantine path
         reads it off the step's readback to depart a NaN/Inf-poisoned slot
         without ever shipping the (B, V) logits to the host.
+
+        ``with_counts``: the layers' step counters come last (see
+        :meth:`decode_step`).
         """
-        logits, new_cache = self.decode_step(params, token_t, cache, pos,
-                                             share=share)
+        kw = {"with_counts": True} if with_counts else {}
+        logits, new_cache, *counts = self.decode_step(
+            params, token_t, cache, pos, share=share, **kw)
         tok = L.sample_step(logits, samp["seed"], pos + 1, samp["temp"],
                             samp["top_k"], samp["top_p"], samp["min_p"])
+        out = (tok, new_cache)
         if with_flags:
-            ok = jnp.isfinite(logits).all(axis=-1)
-            return tok, ok, new_cache
-        return tok, new_cache
+            out = (tok, jnp.isfinite(logits).all(axis=-1), new_cache)
+        return out + tuple(counts)
 
     def _decode_rows(self, params, cfg, x_t, cache, pos, layer_xs,
                      share=None):
@@ -828,10 +895,12 @@ class LM:
         hook gets the whole stacked cache and ``li``, and flash-decode
         reads layer ``li``'s K/V where they lie.  Under prefix sharing the
         scan reads through the composed view but the scatter targets the
-        raw arena — shared rows are never written.
+        raw arena — shared rows are never written.  Returns the layers'
+        step counters third (None when the family counts nothing).
         """
         read = cache if share is None \
             else self._share_view(cache, share[0], share[1])
+        layers, experts = _split_resident(params["layers"])
 
         def block(x_t, inp):
             if layer_xs is None:
@@ -839,10 +908,12 @@ class LM:
                 extra = None
             else:
                 lp, li, extra = inp
-            return self._layer_decode_rows(lp, cfg, x_t, read, li, pos, extra)
+            return self._layer_decode_rows(_with_resident(lp, experts, li),
+                                           cfg, x_t, read, li, pos, extra)
 
         layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-        xs = (params["layers"], layer_ids) if layer_xs is None \
-            else (params["layers"], layer_ids, layer_xs)
+        xs = (layers, layer_ids) if layer_xs is None \
+            else (layers, layer_ids, layer_xs)
         x_t, emits = lax.scan(block, x_t, xs)
-        return x_t, self._rows_scatter(cache, emits, pos)
+        emits, counts = _split_counts(emits)
+        return x_t, self._rows_scatter(cache, emits, pos), counts

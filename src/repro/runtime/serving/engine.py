@@ -155,6 +155,13 @@ def _per_model(build):
     return get
 
 
+def _counted(model) -> dict:
+    """The keyword that makes a family's step also return the counters its
+    layers emit (``model.counters``, e.g. MoE's rows routed here); nothing
+    for a family that counts none, whose steps stay as they were."""
+    return {"with_counts": True} if getattr(model, "counters", ()) else {}
+
+
 # Ownership discipline for donated device state: the engine owns exactly one
 # live generation of (tokens, cache, pos, active); every jitted mutation
 # below *donates* those inputs and the engine immediately rebinds its
@@ -177,9 +184,9 @@ def _compiled_decode(model, donate):
         # key material lives in device state, so donating ``samp`` (it
         # passes through unchanged, aliased in place) cannot perturb a
         # stream across donation generations.
-        sampled, ok, cache = model.decode_and_sample(params, tokens, cache,
-                                                     pos, samp,
-                                                     with_flags=True)
+        sampled, ok, cache, *counts = model.decode_and_sample(
+            params, tokens, cache, pos, samp, with_flags=True,
+            **_counted(model))
         # dead slots: keep the old token (tail-undisturbed) & freeze pos
         tokens = masking.apply_mask(tokens, sampled, active == 1)
         pos = pos + active
@@ -193,8 +200,9 @@ def _compiled_decode(model, donate):
         # rides the same readback: a (B,) bool per-slot health flag (the
         # slot's logits row is entirely finite) the drain checks before
         # committing — a NaN/Inf-poisoned slot is quarantined without the
-        # (B, V) logits ever leaving the device.
-        return tokens, cache, pos, active, samp, sampled, ok
+        # (B, V) logits ever leaving the device.  A counting family's step
+        # counters ride last, read with the tokens.
+        return (tokens, cache, pos, active, samp, sampled, ok, *counts)
     return jax.jit(step, donate_argnums=(1, 2, 3, 4, 5) if donate else ())
 
 
@@ -210,12 +218,13 @@ def _compiled_decode_greedy(model, donate):
     submitted are dropped by the engine's slot-generation staleness guard
     (activation bumps the generation)."""
     def step(params, tokens, cache, pos, active, samp):
-        logits, cache = model.decode_step(params, tokens, cache, pos)
+        logits, cache, *counts = model.decode_step(params, tokens, cache, pos,
+                                                   **_counted(model))
         sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         ok = jnp.isfinite(logits).all(axis=-1)
         tokens = masking.apply_mask(tokens, sampled, active == 1)
         pos = pos + active
-        return tokens, cache, pos, active, samp, sampled, ok
+        return (tokens, cache, pos, active, samp, sampled, ok, *counts)
     return jax.jit(step, donate_argnums=(1, 2, 3, 4, 5) if donate else ())
 
 
@@ -230,12 +239,14 @@ def _compiled_decode_shared(model, donate):
     identity, so one executable serves mixed shared/unshared batches
     bit-identically to the unshared twin."""
     def step(params, tokens, cache, pos, active, samp, share):
-        sampled, ok, cache = model.decode_and_sample(
+        sampled, ok, cache, *counts = model.decode_and_sample(
             params, tokens, cache, pos, samp,
-            share=(share["src"], share["len"]), with_flags=True)
+            share=(share["src"], share["len"]), with_flags=True,
+            **_counted(model))
         tokens = masking.apply_mask(tokens, sampled, active == 1)
         pos = pos + active
-        return tokens, cache, pos, active, samp, share, sampled, ok
+        return (tokens, cache, pos, active, samp, share, sampled, ok,
+                *counts)
     return jax.jit(step,
                    donate_argnums=(1, 2, 3, 4, 5, 6) if donate else ())
 
@@ -243,13 +254,15 @@ def _compiled_decode_shared(model, donate):
 @_per_model
 def _compiled_decode_greedy_shared(model, donate):
     def step(params, tokens, cache, pos, active, samp, share):
-        logits, cache = model.decode_step(
-            params, tokens, cache, pos, share=(share["src"], share["len"]))
+        logits, cache, *counts = model.decode_step(
+            params, tokens, cache, pos, share=(share["src"], share["len"]),
+            **_counted(model))
         sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         ok = jnp.isfinite(logits).all(axis=-1)
         tokens = masking.apply_mask(tokens, sampled, active == 1)
         pos = pos + active
-        return tokens, cache, pos, active, samp, share, sampled, ok
+        return (tokens, cache, pos, active, samp, share, sampled, ok,
+                *counts)
     return jax.jit(step,
                    donate_argnums=(1, 2, 3, 4, 5, 6) if donate else ())
 
@@ -335,10 +348,10 @@ def _compiled_prefill_chunk(model, donate):
     arena (no extract/insert round-trip — the bytes written are the
     chunk's rows).  ``slot``, ``start`` and ``last_idx`` are traced — the
     only compile key is the chunk length, so compiles are bounded by the
-    bucket set."""
+    bucket set.  A counting family's step counters come third."""
     def chunk_step(params, big_cache, tokens, slot, start, last_idx):
         return model.prefill_chunk(params, tokens, big_cache, slot, start,
-                                   last_idx)
+                                   last_idx, **_counted(model))
     return jax.jit(chunk_step, donate_argnums=(1,) if donate else ())
 
 
@@ -353,7 +366,7 @@ def _compiled_prefill_chunk_shared(model, donate):
                    share_src, share_len):
         return model.prefill_chunk(params, tokens, big_cache, slot, start,
                                    last_idx, share_src=share_src,
-                                   share_len=share_len)
+                                   share_len=share_len, **_counted(model))
     return jax.jit(chunk_step, donate_argnums=(1,) if donate else ())
 
 
@@ -555,13 +568,15 @@ class ServingEngine:
         self.health = (HealthMonitor(config.health)
                        if config.health is not None else None)
 
-        # device state: the slot batch
+        # device state: the slot batch; a slot no request has used yet is
+        # parked like a retired one (it reads and writes no arena row, and
+        # routes no row to an expert)
         self._tokens = jnp.zeros((max_slots,), jnp.int32)
-        self._pos = jnp.zeros((max_slots,), jnp.int32)
+        self._pos = jnp.full((max_slots,), PARKED_POS, jnp.int32)
         self._active = jnp.zeros((max_slots,), jnp.int32)
         # the host's copies of pos/active, kept in step with every update
         # the host sends (the decode step's pos += active included)
-        self._host_pos = np.zeros((max_slots,), np.int64)
+        self._host_pos = np.full((max_slots,), PARKED_POS, np.int64)
         self._host_active = np.zeros((max_slots,), np.int64)
         # per-slot sampling params (greedy until a sampled admission);
         # threaded through — and donated with — every decode step
@@ -699,12 +714,23 @@ class ServingEngine:
                       # K/V strips flash-decode fetches per layer, summed
                       # over decode steps
                       "decode_kv_strips": 0}
+        # the family's step counters (MoE: rows routed to the experts held
+        # here, held experts given a row), summed over layers and steps:
+        # every step under its own name, decode steps alone under
+        # ``decode_<name>``.  They are read with the tokens of the decode
+        # step they rode with (a chunk's with the next decode step's), so
+        # reading them adds no wait.
+        self._counters = tuple(getattr(model, "counters", ()))
+        for name in self._counters:
+            self.stats[name] = self.stats["decode_" + name] = 0
+        self._chunk_counts: list = []
         # decode-state buffers are donated into each step, so the queue
         # tracks a never-donated readback output (the sampled vector,
-        # out[-2] — out[-1] is the ok-flag readback) for backpressure; its
-        # blocks count into the engine's host_blocked_s
+        # ahead of the ok-flag readback and any step counters) for
+        # backpressure; its blocks count into the engine's host_blocked_s
+        lag = 3 if self._counters else 2
         self._queue = DispatchQueue(self._submit_decode, depth=self.depth,
-                                    inflight_of=lambda out: out[-2],
+                                    inflight_of=lambda out: out[-lag],
                                     counts=self.stats)
         if self._injector is not None:
             # live view of per-site fire counts (aliased, not copied)
@@ -1239,14 +1265,15 @@ class ServingEngine:
             last_idx = real - 1
             if self.prefix_sharing:
                 src = st.share_src if st.share_src is not None else st.slot
-                logits, self._cache = self._chunk_fn(
+                logits, self._cache, *counts = self._chunk_fn(
                     self.params, self._cache, jnp.asarray(chunk)[None, :],
                     jnp.int32(st.slot), jnp.int32(start), jnp.int32(last_idx),
                     jnp.int32(src), jnp.int32(st.share_len))
             else:
-                logits, self._cache = self._chunk_fn(
+                logits, self._cache, *counts = self._chunk_fn(
                     self.params, self._cache, jnp.asarray(chunk)[None, :],
                     jnp.int32(st.slot), jnp.int32(start), jnp.int32(last_idx))
+            self._chunk_counts += counts
             if self.spec is not None:
                 # lockstep draft ingestion: the identical chunk goes into
                 # the draft arena (same slot, same rows; logits discarded),
@@ -1450,6 +1477,8 @@ class ServingEngine:
             self.stats["decode_kv_strips"] += kv_strips
             # rebind to the outputs: the submitted buffers were donated and
             # are dead from here on
+            out, counts = ((out[:-1], out[-1:]) if self._counters
+                           else (out, ()))
             if self.prefix_sharing:
                 (self._tokens, self._cache, self._pos, self._active,
                  self._samp, self._share, read, okv) = out
@@ -1459,19 +1488,35 @@ class ServingEngine:
             self.stats["decode_steps"] += 1
             snapshot = {slot: (st, self._slot_gen[slot])
                         for slot, st in self.scheduler.running.items()}
-            self._pending.append((read, okv, snapshot))
+            self._pending.append((read, okv, counts, self._chunk_counts,
+                                  snapshot))
+            self._chunk_counts = []
+
+    def _add_counts(self, counts, prefixes) -> None:
+        """Add read-back step counters to ``stats`` under each prefix."""
+        for vec in counts:
+            for name, v in zip(self._counters, np.asarray(vec).tolist()):
+                for prefix in prefixes:
+                    self.stats[prefix + name] += v
 
     def _drain_pending(self, *, limit: int) -> None:
         """Process token outputs older than ``limit`` steps (blocking only
         on steps the queue has already forced to completion)."""
+        if limit == 0 and self._chunk_counts:
+            # the final drain: chunks no decode step followed yet
+            self._add_counts(self._chunk_counts, ("",))
+            self._chunk_counts = []
         if len(self._pending) <= limit:
             return
         with spans.span("serving.retire"):
             while len(self._pending) > limit:
-                tokens, okv, snapshot = self._pending.popleft()
+                tokens, okv, counts, chunk_counts, snapshot = \
+                    self._pending.popleft()
                 with spans.wait("readback", self.stats):
                     host_tokens = np.asarray(tokens)
                     host_ok = np.asarray(okv)
+                    self._add_counts(counts, ("", "decode_"))
+                    self._add_counts(chunk_counts, ("",))
                 for slot, (st, gen) in snapshot.items():
                     # stale entries: the request left this slot (finished
                     # or preempted) after the step was submitted, was still

@@ -122,8 +122,8 @@ class SpecController:
 
     #: families whose chunk-path logits are bit-identical to decode-path
     #: logits — the precondition for the determinism contract.  Recurrent
-    #: families (ssm/hybrid) rewind state, not positions; MoE chunk logits
-    #: couple tokens through expert capacity (see moe.moe_layer_chunk).
+    #: families (ssm/hybrid) rewind state, not positions; MoE's dropless
+    #: layer is per-row, but no test pins its verify against its decode.
     _OK_FAMILIES = ("dense",)
 
     def __init__(self, target_cfg, spec: SpecConfig):

@@ -37,8 +37,7 @@ def rng_key():
 # tiny non-dense family configs, shared by the serving-path suites
 # (test_chunked_prefill / test_zero_copy).  One source of truth —
 # configs.base.tiny_family_configs — also feeds bench_serving's family
-# claims, so the pinned regime (notably MoE's never-binding
-# capacity_factor) cannot drift between tests and bench.
+# claims, so the pinned regime cannot drift between tests and bench.
 # ---------------------------------------------------------------------------
 
 FAMILY_CFGS = None      # populated lazily so conftest import stays free of

@@ -279,8 +279,8 @@ def test_prefill_chunk_matches_monolithic(tiny_model):
 # ---------------------------------------------------------------------------
 # per-family chunked prefill: MoE / SSM / hybrid on the rows/arena contract
 # (tiny family configs + the module-scoped ``family_model`` fixture live in
-# conftest.py, shared with test_zero_copy so the pinned regime — notably
-# MoE's never-binding capacity_factor — cannot drift between suites)
+# conftest.py, shared with test_zero_copy so the pinned regime cannot
+# drift between suites)
 # ---------------------------------------------------------------------------
 
 def test_every_lm_family_supports_chunked_prefill(family_model):
@@ -354,7 +354,7 @@ def test_family_prefill_chunk_matches_monolithic(family_model):
 def test_family_engine_chunked_matches_sequential(family_model, depth):
     """Chunked prefill interleaved with decode, slots < requests, mixed
     prompt lengths -> token-exact vs sequential monolithic generation for
-    MoE (capacity unbound), SSM and hybrid."""
+    MoE (dropless), SSM and hybrid."""
     cfg, model, params = family_model
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, cfg.vocab, n).astype(np.int32)

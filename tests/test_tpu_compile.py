@@ -197,3 +197,98 @@ def test_llama_chunk_step_compiles(one_chip, llama, pallas_mode):
                           _spec(one_chip, (1, 512), jnp.int32),
                           scalar, scalar, scalar).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the qwen3-30b-a3b-24l cell: 24 layers, experts 0-15 of 128 held, 24 slots
+# x 6177 rows of bf16 K/V (4 KV heads of 128, groups of 8)
+MOE_SLOTS, MOE_ROWS = 24, 6177
+
+
+@pytest.mark.parametrize("rows,k,n", [(24 * 8, 2048, 768),
+                                      (512 * 8, 768, 2048)])
+def test_expert_gmm_compiles(one_chip, rows, k, n):
+    """The grouped matmul at the cell's decode and 512-token chunk row
+    buffers, gate/up and down shapes, 16 held experts of 24 stacked
+    layers read at a traced layer index."""
+    from repro.kernels import ops
+    s = lambda shape, dt: _spec(one_chip, shape, dt)
+    _compile(lambda a, w, sz, li: ops.expert_gmm(a, w, sz, layer=li,
+                                                 mode="pallas"),
+             s((rows, k), jnp.bfloat16), s((24, 16, k, n), jnp.bfloat16),
+             s((16,), jnp.int32), s((), jnp.int32))
+
+
+@pytest.fixture(scope="module")
+def qwen3_moe(one_chip):
+    """The benchmark's Qwen3-30B-A3B share at published widths: model,
+    abstract params and the cell's abstract bf16 arena."""
+    import dataclasses
+    from repro.configs.base import MoEConfig
+    from repro.models import registry
+    full = registry.config("qwen3-moe-30b-a3b")
+    cfg = dataclasses.replace(full, n_layers=24, moe=dataclasses.replace(
+        full.moe, experts_held=16, first_expert=0))
+    model = registry.build_model(cfg)
+    params = _abstract(one_chip, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0))))
+    cache = _abstract(one_chip, jax.eval_shape(
+        lambda: model.init_cache(MOE_SLOTS, MOE_ROWS, kv_format="bf16")))
+    assert isinstance(cfg.moe, MoEConfig)
+    return model, params, cache
+
+
+def _no_capacity_buffer(text):
+    """No (experts, capacity, d) dispatch buffer, and no one layer's
+    expert weights copied out of the (24, 16, ...) stack: the expert layer
+    reads the stack in place, so no op outputs an array of (1 x) 16 x
+    ... x 2048 or (1 x) 16 x 2048 x ...."""
+    bufs = [(name, dims) for name, dims, _ in _hlo_outputs(text)
+            if re.fullmatch(r"(1,)?16,(\d+,2048|2048,\d+)", dims)]
+    assert not bufs, bufs
+
+
+def test_qwen3_moe_decode_step_fits_v5e(one_chip, qwen3_moe, pallas_mode):
+    """The served MoE decode step (greedy twin), arena donated: the expert
+    layer is ``expert_gmm`` beside ``flash_decode``, the step returns its
+    routing counters, and it fits one chip."""
+    from repro.runtime.serving import engine, sampling
+    model, params, cache = qwen3_moe
+    vec = _spec(one_chip, (MOE_SLOTS,), jnp.int32)
+    samp = _abstract(one_chip, sampling.init_slot_state(MOE_SLOTS))
+    step = engine._compiled_decode_greedy(model, True)
+    lowered = step.lower(params, vec, cache, vec, vec, samp)
+    assert lowered.out_info[-1].shape == (2,)        # the counters
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "%expert_gmm" in text and "%flash_decode" in text
+    _no_capacity_buffer(text)
+    mem = compiled.memory_analysis()
+    resident = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.alias_size_in_bytes > 0
+    assert resident < V5E_HBM_BYTES, mem
+
+
+def test_qwen3_moe_chunk_step_compiles(one_chip, qwen3_moe, pallas_mode):
+    """The served 512-token MoE chunk step, arena donated."""
+    from repro.runtime.serving import engine
+    model, params, cache = qwen3_moe
+    scalar = _spec(one_chip, (), jnp.int32)
+    step = engine._compiled_prefill_chunk(model, True)
+    compiled = step.lower(params, cache,
+                          _spec(one_chip, (1, 512), jnp.int32),
+                          scalar, scalar, scalar).compile()
+    text = compiled.as_text()
+    assert "%expert_gmm" in text
+    _no_capacity_buffer(text)
+    # the chunk's rows are scattered into the 4-KV-head arena in place:
+    # no copy of an arena leaf, and the step fits one chip
+    leaf = cache["k"].size * cache["k"].dtype.itemsize
+    copies = [(name, dims) for name, dims, nbytes in _hlo_outputs(text)
+              if nbytes >= leaf and re.search(r"copy|transpose", name)]
+    assert not copies, copies
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < leaf // 4, mem
+    assert (mem.argument_size_in_bytes - mem.alias_size_in_bytes
+            + mem.output_size_in_bytes + mem.temp_size_in_bytes
+            < V5E_HBM_BYTES), mem

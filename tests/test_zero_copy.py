@@ -34,8 +34,7 @@ TINY = ArchConfig(name="tiny-zc", family="dense", n_layers=2, d_model=32,
                   param_dtype="float32", act_dtype="float32", max_seq=64)
 
 # non-dense family configs + the ``family_model`` fixture are shared with
-# test_chunked_prefill via conftest.py (one pinned regime — notably MoE's
-# never-binding capacity_factor)
+# test_chunked_prefill via conftest.py (one pinned regime)
 
 SLOTS, SEQ, CHUNK = 3, 48, 8
 
@@ -153,8 +152,9 @@ def test_family_chunk_and_decode_reuse_donated_arena_buffer(family_model):
     cache = model.init_cache(SLOTS, SEQ)
     ptrs = _leaf_ptrs(cache)
     toks = jnp.zeros((1, CHUNK), jnp.int32)
-    logits, cache2 = chunk_fn(params, cache, toks, jnp.int32(1),
-                              jnp.int32(0), jnp.int32(CHUNK - 1))
+    # a counting family (MoE) returns its step counters third
+    logits, cache2, *counts = chunk_fn(params, cache, toks, jnp.int32(1),
+                                       jnp.int32(0), jnp.int32(CHUNK - 1))
     _require_donation(cache)
     assert _leaf_ptrs(cache2) == ptrs, \
         f"{cfg.family}: chunk step re-materialised the arena"
@@ -408,7 +408,7 @@ def test_family_preemption_recompute_token_identical_sampled(family_model):
     a token-identical continuation on recompute, with the arena donated
     throughout.  The reference run is the same workload in an unpressured
     pool, so the comparison also pins batch-trajectory invariance (exact
-    for SSM/hybrid; for MoE because the test capacity never binds)."""
+    for SSM/hybrid; for MoE because its serving layer is dropless)."""
     cfg, model, params = family_model
     rng = np.random.default_rng(6)
     prompts = [rng.integers(0, cfg.vocab, n).astype(np.int32)
